@@ -189,18 +189,20 @@ def backfill_bench_rows(path: str | None = None) -> str:
 def ensure_multidevice_harness(count: int, module: str) -> None:
     """Olmax-style multi-device CPU harness (SNIPPETS §1–2): make this
     bench process see ``count`` host platform devices and run under
-    tcmalloc. Call FIRST THING in ``main()`` — the XLA flag only takes
-    effect before the jax backend initializes. tcmalloc can only load
-    at process start, so when the library exists but is not preloaded
-    the process re-execs itself ONCE (``python -m module argv…``) with
-    the full env from ``launch.hostdevices``."""
+    tcmalloc. Only for a process that already runs on the CPU
+    (``JAX_PLATFORMS=cpu``); raises otherwise. Call FIRST THING in
+    ``main()`` — the XLA flag only takes effect before the jax backend
+    initializes. tcmalloc can only load at process start, so when the
+    library exists but is not preloaded the process re-execs itself ONCE
+    (``python -m module argv…``) with the full env from
+    ``launch.hostdevices``."""
     from repro.launch.hostdevices import (
         ensure_host_platform_devices,
         host_device_env,
     )
 
+    env = host_device_env(count)     # raises unless JAX_PLATFORMS=cpu
     ensure_host_platform_devices(count)
-    env = host_device_env(count)
     want = env.get("LD_PRELOAD")
     if (want and want != os.environ.get("LD_PRELOAD")
             and os.environ.get("_REPRO_BENCH_REEXEC") != "1"):

@@ -39,6 +39,7 @@ import numpy as np
 from repro.core.rwsadmm import RWSADMMHparams
 from repro.fl.fleet_trainer import FleetRWSADMMTrainer
 from repro.fl.rwsadmm_trainer import ENGINES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.small import get_model
 
 from .common import (
@@ -145,6 +146,7 @@ def main() -> None:
                     help="CI gate: short run, exits nonzero unless "
                     "scan beats eager at every K")
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.smoke:
         results = run(rounds=40, clients=(40,), walkers=(1, 3, 5),

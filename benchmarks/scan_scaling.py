@@ -47,6 +47,7 @@ Full:                python -m benchmarks.scan_scaling && \
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import time
 
@@ -55,6 +56,7 @@ import numpy as np
 
 from repro.core.rwsadmm import RWSADMMHparams
 from repro.fl.rwsadmm_trainer import ENGINES, RWSADMMTrainer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.small import get_model
 
 from .common import (
@@ -160,6 +162,47 @@ def control_plane(clients=(2000, 10000, 50000), rounds: int = 64,
     return results
 
 
+def lazy_plane_trainer(n: int, capacity: int = 1024, *, mesh=None,
+                       prefetch: bool = False) -> RWSADMMTrainer:
+    """The lazy-plane training workload at population ``n``: the paper's
+    Synthetic(0.5, 0.5) MLR clients behind a ``ClientDataFactory``, a
+    bounded ``capacity``-slot LRU store, and the sparse gauss-markov +
+    link-dropout scenario with the radio range shrunk so the expected
+    degree stays ~12. ``mesh`` (an ``FLSharding``) shards the packed
+    store rows over its "data" axis."""
+    import dataclasses as _dc
+
+    from repro.data import synthetic_lr_factory
+    from repro.scenarios import (
+        LinkConfig,
+        MobilityConfig,
+        ScenarioConfig,
+    )
+
+    # Narrower count tail than the paper default (mean_samples 2.0 vs
+    # 4.0): packed store rows are max_train wide, and one 1-in-a-million
+    # lognormal straggler would pad every slot's row.
+    factory = synthetic_lr_factory(
+        n_clients=n, seed=0, min_samples=20, mean_samples=2.0)
+    model = get_model("mlr", (60,))
+    radio = float(np.sqrt(12.0 / (np.pi * n)))
+    cfg = ScenarioConfig(
+        name="bench_lazy_gm_sparse",
+        mobility=MobilityConfig(model="gauss_markov", radio_range=radio),
+        links=LinkConfig(enabled=True, dropout=True),
+        graph_backend="sparse", neighbor_k_max=32)
+    # Small rollout chunks: the (chunk, n, k_max) neighbor-list stacks
+    # are the biggest transient at n = 10⁶ (≈0.5 GB each at chunk 8) —
+    # the store itself stays capacity-bounded.
+    cfg = _dc.replace(cfg, rollout_chunk=8)
+    return RWSADMMTrainer(
+        model, factory,
+        RWSADMMHparams(beta=10.0, kappa=0.001, epsilon=1e-5),
+        zone_size=8, batch_size=20, solver="closed_form",
+        scenario=cfg, seed=0, store_capacity=capacity,
+        prefetch=prefetch, mesh=mesh)
+
+
 def lazy_plane(clients=(100_000, 1_000_000), rounds: int = 32,
                capacity: int = 1024, *, shard_devices: int | None = None,
                prefetch: bool = False) -> dict:
@@ -173,53 +216,22 @@ def lazy_plane(clients=(100_000, 1_000_000), rounds: int = 32,
     and appends rows to BENCH_scaling.json.
 
     ``shard_devices``: place the packed store over a "data" mesh of
-    that many (host platform) devices — row tag ``scan_shard{d}``; run
-    under ``--shard-devices`` so the devices exist. ``prefetch``: stage
+    that many devices — row tag ``scan_shard{d}``. ``prefetch``: stage
     each next chunk's dataset rows on a host thread while the current
     chunk executes (row tag suffix ``_prefetch``); the timed region
     then pipelines schedule → prefetch → chunk exactly like
     ``run_simulation`` does."""
-    import dataclasses as _dc
-
-    from repro.data import synthetic_lr_factory
-    from repro.scenarios import (
-        LinkConfig,
-        MobilityConfig,
-        ScenarioConfig,
-    )
-
     results: dict = {}
     json_rows = []
     for n in clients:
         reset_peak_rss()
-        # Narrower count tail than the paper default (mean_samples 2.0
-        # vs 4.0): packed store rows are max_train wide, and one 1-in-a-
-        # million lognormal straggler would pad every slot's row.
-        factory = synthetic_lr_factory(
-            n_clients=n, seed=0, min_samples=20, mean_samples=2.0)
-        model = get_model("mlr", (60,))
-        radio = float(np.sqrt(12.0 / (np.pi * n)))
-        cfg = ScenarioConfig(
-            name="bench_lazy_gm_sparse",
-            mobility=MobilityConfig(model="gauss_markov",
-                                    radio_range=radio),
-            links=LinkConfig(enabled=True, dropout=True),
-            graph_backend="sparse", neighbor_k_max=32)
-        # Small rollout chunks: the (chunk, n, k_max) neighbor-list
-        # stacks are the biggest transient at n = 10⁶ (≈0.5 GB each at
-        # chunk 8) — the store itself stays capacity-bounded.
-        cfg = _dc.replace(cfg, rollout_chunk=8)
         mesh = None
         if shard_devices:
             from repro.fl.sharding import FLSharding
 
             mesh = FLSharding(n_devices=shard_devices)
-        trainer = RWSADMMTrainer(
-            model, factory,
-            RWSADMMHparams(beta=10.0, kappa=0.001, epsilon=1e-5),
-            zone_size=8, batch_size=20, solver="closed_form",
-            scenario=cfg, seed=0, store_capacity=capacity,
-            prefetch=prefetch, mesh=mesh)
+        trainer = lazy_plane_trainer(n, capacity, mesh=mesh,
+                                     prefetch=prefetch)
         state = trainer.init_state(jax.random.PRNGKey(0))
         rng = np.random.default_rng(0)
         sched = trainer.schedule(rounds, rng, start_round=0)
@@ -266,7 +278,7 @@ def lazy_plane(clients=(100_000, 1_000_000), rounds: int = 32,
             store_misses=c["misses"], store_evictions=c["evictions"],
             **extra))
         results[n] = sec
-        del trainer, state, sched, stacked, factory
+        del trainer, state, sched, stacked
     write_bench_rows(json_rows)
     return results
 
@@ -297,20 +309,25 @@ def main() -> None:
                     help="lazy-plane store capacity (resident slots)")
     ap.add_argument("--shard-devices", type=int, default=None,
                     help="lazy plane: shard the packed store over this "
-                    "many host platform devices (olmax-style multi-"
-                    "device CPU harness; re-execs under tcmalloc)")
+                    "many devices (under JAX_PLATFORMS=cpu: virtual host "
+                    "devices, olmax-style harness, re-execs under "
+                    "tcmalloc)")
     ap.add_argument("--prefetch", action="store_true",
                     help="lazy plane: async next-chunk dataset staging")
     ap.add_argument("--assert-rss-mb", type=float, default=None,
                     help="exit nonzero if peak RSS exceeds this (the "
                     "sparse-backend / lazy-plane CI memory gate)")
     args = ap.parse_args()
-    if args.shard_devices:
+    if args.shard_devices and os.environ.get("JAX_PLATFORMS") == "cpu":
         from .common import ensure_multidevice_harness
 
-        # Must precede the first computation (backend init).
+        # CPU rehearsal: virtual host devices. Must precede the first
+        # computation (backend init). On an accelerator the store shards
+        # over the real devices, and FLSharding refuses a count the
+        # host does not have.
         ensure_multidevice_harness(args.shard_devices,
                                    "benchmarks.scan_scaling")
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if args.lazy:
         lazy_plane(clients=tuple(args.lazy_clients),

@@ -13,9 +13,9 @@ The matrix mirrors the pinned test surface:
   is covered;
 * engine:   ``eager`` (the per-round jitted step) and the compiled
   ``scan`` / ``scan_fused`` chunk drivers;
-* plane:    ``dense`` (dataset baked as closure const — deliberate) and
-  ``lazy`` (ClientStore data enters as a traced argument — enforced by
-  the baked-constant budget);
+* plane:    ``dense`` (stacked DeviceData) and ``lazy`` (ClientStore
+  rows); both hand their data to the step as a traced argument, which
+  the baked-constant budget enforces;
 * sharding: unsharded and a 1-device ``FLSharding`` mesh (the in-process
   sharded-path pin from the sharded-plane tests) — the sharded chunk
   must donate its carry, the unsharded one must not.
@@ -32,17 +32,12 @@ from typing import Iterable, Sequence
 import jax
 import numpy as np
 
-from .jaxpr_audit import DEFAULT_CONST_BUDGET, ClosureAudit, audit_closure
+from .jaxpr_audit import ClosureAudit, audit_closure
 
 N_CLIENTS = 8
 EAGER_ROUNDS = 2
 CHUNK_ROUNDS = 3
 SCAN_ENGINES = ("scan", "scan_fused")
-
-#: slack on top of the measured dense-plane bytes (model params, masks,
-#: schedule constants…)
-_CONST_SLACK = 256 * 1024
-
 
 @dataclasses.dataclass(frozen=True)
 class CellSpec:
@@ -83,7 +78,6 @@ class CapturedClosure:
     fn: object
     args: tuple
     kwargs: dict
-    const_budget: int
     expect_donation: bool | None
 
     @property
@@ -93,7 +87,6 @@ class CapturedClosure:
     def audit(self) -> ClosureAudit:
         report = audit_closure(
             self.name, self.fn, self.args, self.kwargs,
-            const_budget=self.const_budget,
             expect_donation=self.expect_donation)
         report.name = self.key
         for i, f in enumerate(report.findings):
@@ -118,11 +111,6 @@ def _workload():
     factory = factory_from_federated(fed)
     model = get_model("mlr", (28, 28, 1))
     return dense, factory, model
-
-
-def _tree_nbytes(tree) -> int:
-    return int(sum(np.asarray(leaf).nbytes
-                   for leaf in jax.tree_util.tree_leaves(tree)))
 
 
 def build_cell(spec: CellSpec):
@@ -155,16 +143,6 @@ def build_cell(spec: CellSpec):
                                sync_every=3, fleet_mode=mode, **kw)
 
 
-def _const_budget(trainer, spec: CellSpec) -> int:
-    """Per-closure const byte budget: the dense plane deliberately bakes
-    the dataset, so its budget is the measured data size plus slack; the
-    lazy plane's data is a traced argument, so anything near the store's
-    packed bytes in the consts means it leaked back in."""
-    if spec.plane == "dense":
-        return _tree_nbytes(trainer.data) + _CONST_SLACK
-    return DEFAULT_CONST_BUDGET
-
-
 def run_cell(spec: CellSpec,
              engines: Sequence[str] = ("eager",) + SCAN_ENGINES,
              ) -> list[CapturedClosure]:
@@ -172,7 +150,6 @@ def run_cell(spec: CellSpec,
     jitted step call the drivers made, audit-ready."""
     captured: list[CapturedClosure] = []
     trainer = build_cell(spec)
-    budget = _const_budget(trainer, spec)
 
     for engine in engines:
         # Fresh state per engine: the sharded chunk donates its carry,
@@ -198,8 +175,7 @@ def run_cell(spec: CellSpec,
             expect = spec.sharded if name.startswith("chunk") else None
             captured.append(CapturedClosure(
                 cell=spec.key, engine=engine, name=name, fn=fn,
-                args=args, kwargs=kwargs, const_budget=budget,
-                expect_donation=expect))
+                args=args, kwargs=kwargs, expect_donation=expect))
     return captured
 
 

@@ -188,40 +188,23 @@ class TrainerBase:
             logits = model.apply(params, x, train=False)
             return accuracy(logits, y, m), cross_entropy(logits, y, m)
 
-        self._eval_row = eval_row
-        # Row-based evaluation over explicit test arrays — the lazy
-        # plane's eval path (the packed store rows ARE the data; there
-        # is no (n, …) stack to close over).
+        # Row-based evaluation over explicit test arrays, shared by both
+        # planes: the dense plane passes its stacked (n, …) test columns,
+        # the lazy plane its packed store rows. The test data is a traced
+        # argument, never a closure constant baked into the executable.
         self.eval_rows_stacked = jax.jit(
             jax.vmap(eval_row, in_axes=(0, 0, 0, 0)))
         self.eval_rows_shared = jax.jit(
             jax.vmap(eval_row, in_axes=(None, 0, 0, 0)))
 
         if lazy:
-            return   # dense eval/train closures below capture self.data
-
-        def eval_client(params, client):
-            logits = model.apply(params, data.x_test[client], train=False)
-            m = data.mask_test[client]
-            return (accuracy(logits, data.y_test[client], m),
-                    cross_entropy(logits, data.y_test[client], m))
-
-        self._eval_client = eval_client
+            return   # the dense diagnostics below capture self.data
 
         def train_loss_client(params, client, key):
             xb, yb = sample_batch(data, client, key, self.batch_size)
             return loss_fn(params, xb, yb, None)
 
         self._train_loss_client = train_loss_client
-
-        # Personalized evaluation over all clients: params stacked (n, ...).
-        self.eval_stacked = jax.jit(
-            jax.vmap(eval_client, in_axes=(0, 0))
-        )
-        # One shared model evaluated on every client's test set.
-        self.eval_shared = jax.jit(
-            jax.vmap(eval_client, in_axes=(None, 0))
-        )
 
     # -- local inner loops ------------------------------------------------
     def make_local_sgd(self, lr: float, steps: int) -> Callable:
@@ -261,15 +244,18 @@ class TrainerBase:
             # (materialized) clients instead.
             return self._evaluate_lazy(state)
         out: dict[str, float] = {}
+        d = self.data
         pers = self.personalized_params(state)
         if pers is not None:
-            acc, loss = self.eval_stacked(pers, jnp.arange(self.n_clients))
+            acc, loss = self.eval_rows_stacked(pers, d.x_test, d.y_test,
+                                               d.mask_test)
             out["acc_personalized"] = float(jnp.mean(acc))
             out["acc_personalized_std"] = float(jnp.std(acc))
             out["loss_personalized"] = float(jnp.mean(loss))
         glob = self.global_params(state)
         if glob is not None:
-            acc, loss = self.eval_shared(glob, jnp.arange(self.n_clients))
+            acc, loss = self.eval_rows_shared(glob, d.x_test, d.y_test,
+                                              d.mask_test)
             out["acc_global"] = float(jnp.mean(acc))
             out["loss_global"] = float(jnp.mean(loss))
         out["acc"] = out.get("acc_personalized", out.get("acc_global", 0.0))

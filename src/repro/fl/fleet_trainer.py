@@ -166,15 +166,15 @@ class FleetRWSADMMTrainer(RWSADMMTrainer):
     # engines run literally the same computation per round.
     # ------------------------------------------------------------------
     def _rr_step_impl(self, state: FleetState, idx, mask, n_i, a, sync,
-                      key, iw=None, gid=None, data=None, *,
+                      key, iw=None, gid=None, *, data: DeviceData,
                       use_fused: bool = False):
         """Round-robin fleet round: walker ``a`` serves one zone against
         its own token (dynamic_index into the stack), then an optional
         rendezvous averages the stack. ``iw`` (biased walk policies) is
         the active walker's importance weight, threaded into the shared
-        Eq. 31 round body's y fold; ``gid``/``data`` thread the lazy
-        client plane through it (slot-indexed zone + packed store data,
-        see :meth:`RWSADMMTrainer._round_impl`)."""
+        Eq. 31 round body's y fold; ``data`` is the traced dataset and
+        ``gid`` the lazy plane's global ids (see
+        :meth:`RWSADMMTrainer._round_impl`)."""
         y_k = jax.tree_util.tree_map(
             lambda t: jax.lax.dynamic_index_in_dim(t, a, 0, keepdims=False),
             state.tokens)
@@ -184,7 +184,7 @@ class FleetRWSADMMTrainer(RWSADMMTrainer):
                                round=state.base.server.round),
             visited=state.base.visited)
         new_base, loss = self._round_impl(base, idx, mask, n_i, key, iw,
-                                          gid, data,
+                                          gid, data=data,
                                           use_fused=use_fused)
         tokens = jax.tree_util.tree_map(
             lambda t, y: jax.lax.dynamic_update_index_in_dim(t, y, a, 0),
@@ -193,16 +193,16 @@ class FleetRWSADMMTrainer(RWSADMMTrainer):
                           tokens=_rendezvous(tokens, sync)), loss
 
     def _sim_step_impl(self, state: FleetState, idx, mask, n_i, sync,
-                       key, iw=None, gid=None, data=None, *,
+                       key, iw=None, gid=None, *, data: DeviceData,
                        use_fused: bool = False):
         """Simultaneous fleet wall step: K disjoint zones (idx/mask are
         (K, Z)) update in one vmapped Eq. 31 pass, each against its own
         walker's token; κ decays once per wall step. ``iw`` (biased walk
         policies) carries each walker's importance weight (K,); the
-        per-walker token folds are rescaled by it post hoc. Lazy plane:
-        ``idx`` holds store slots, ``gid`` the (K, Z) global ids, and
-        ``data`` the packed store rows as a traced argument."""
-        data = self.data if data is None else data
+        per-walker token folds are rescaled by it post hoc. ``data`` is
+        the traced dataset (dense stack or packed store rows). Lazy
+        plane: ``idx`` holds store slots, ``gid`` the (K, Z) global
+        ids."""
         clients = state.base.clients
         hp, kappa = self.hp, state.base.server.kappa
         k_walkers, zone = idx.shape
@@ -292,12 +292,7 @@ class FleetRWSADMMTrainer(RWSADMMTrainer):
         latency_s, energy_j = self._price(graph, i_k, idx, mask)
         key = markov.round_key(rng)
         sync = float((rnd + 1) % max(self.sync_every, 1) == 0)
-        kwargs = {}
-        if self.store is not None:
-            state, zone_idx = self._ensure_round(state, idx)
-            kwargs = {"gid": jnp.asarray(idx), "data": self.store.data}
-        else:
-            zone_idx = idx
+        state, zone_idx, kwargs = self._round_plane(state, idx)
         args = [state, jnp.asarray(zone_idx), jnp.asarray(mask),
                 jnp.asarray(float(n_i)), jnp.asarray(k, jnp.int32),
                 jnp.asarray(sync, jnp.float32), key]
@@ -332,12 +327,7 @@ class FleetRWSADMMTrainer(RWSADMMTrainer):
             avail=self.scenario.availability())
         key = markov.round_key(rng)
         sync = float((rnd + 1) % max(self.sync_every, 1) == 0)
-        kwargs = {}
-        if self.store is not None:
-            state, zone_idx = self._ensure_round(state, idx)
-            kwargs = {"gid": jnp.asarray(idx), "data": self.store.data}
-        else:
-            zone_idx = idx
+        state, zone_idx, kwargs = self._round_plane(state, idx)
         args = [state, jnp.asarray(zone_idx), jnp.asarray(mask),
                 jnp.asarray(n_i), jnp.asarray(sync, jnp.float32), key]
         if self._use_iw:
@@ -426,13 +416,14 @@ class FleetRWSADMMTrainer(RWSADMMTrainer):
                         cols = cols + (iws,)
                     return jax.lax.scan(body, state, cols)
             elif mode == "roundrobin":
-                def chunk(state, idx, mask, n_i, keys, walker, sync,
+                def chunk(state, data, idx, mask, n_i, keys, walker, sync,
                           iws=None):
                     def body(carry, per):
                         i_r, m_r, ni_r, k_r, a_r, s_r = per[:6]
                         w_r = per[6] if use_iw else None
                         new_state, loss = step(carry, i_r, m_r, ni_r,
-                                               a_r, s_r, k_r, w_r)
+                                               a_r, s_r, k_r, w_r,
+                                               data=data)
                         return new_state, (loss,
                                            new_state.base.server.kappa)
 
@@ -457,12 +448,13 @@ class FleetRWSADMMTrainer(RWSADMMTrainer):
                         cols = cols + (iws,)
                     return jax.lax.scan(body, state, cols)
             else:
-                def chunk(state, idx, mask, n_i, keys, sync, iws=None):
+                def chunk(state, data, idx, mask, n_i, keys, sync,
+                          iws=None):
                     def body(carry, per):
                         i_r, m_r, ni_r, k_r, s_r = per[:5]
                         w_r = per[5] if use_iw else None
                         new_state, loss = step(carry, i_r, m_r, ni_r,
-                                               s_r, k_r, w_r)
+                                               s_r, k_r, w_r, data=data)
                         return new_state, (loss,
                                            new_state.base.server.kappa)
 
@@ -478,12 +470,11 @@ class FleetRWSADMMTrainer(RWSADMMTrainer):
                 fn = jax.jit(chunk)
             self._fleet_chunk_fns[(mode, engine)] = fn
 
-        args = []
         if lazy:
-            args += [self.store.data, jnp.asarray(slot_idx),
-                     jnp.asarray(sched.idx)]
+            args = [self.store.data, jnp.asarray(slot_idx),
+                    jnp.asarray(sched.idx)]
         else:
-            args.append(jnp.asarray(sched.idx))
+            args = [self.data, jnp.asarray(sched.idx)]
         args += [jnp.asarray(sched.mask), jnp.asarray(sched.n_i),
                  jnp.asarray(sched.keys)]
         if mode == "roundrobin":

@@ -242,17 +242,17 @@ class RWSADMMTrainer(TrainerBase):
 
     # ------------------------------------------------------------------
     def _round_impl(self, state: RWSADMMState, zone_idx, zone_mask, n_i,
-                    key, iw=None, gid=None, data=None, *,
+                    key, iw=None, gid=None, *, data: DeviceData,
                     use_fused: bool = False):
-        # Dense plane: zone_idx are global client ids, gid/data are None
-        # (empty pytrees under jit — the seed computation graph is
-        # untouched) and the stacked dataset is a compile-time closure
-        # constant. Lazy plane: zone_idx are STORE SLOTS, ``gid`` carries
-        # the global ids (visited-set bookkeeping), and the packed store
-        # data MUST arrive as a traced argument — a closure over
-        # ``self.store.data`` would bake whatever rows were resident at
-        # trace time into the executable.
-        data = self.data if data is None else data
+        # ``data`` always arrives as a traced argument. Dense plane: the
+        # stacked DeviceData, zone_idx are global client ids and gid is
+        # None (an empty pytree under jit). Baking the dataset in as a
+        # closure constant would copy it into every executable and, at
+        # the paper CNN's 0.5 GB, cost the TPU compiler seconds per
+        # program. Lazy plane: the packed store data, zone_idx are STORE
+        # SLOTS and ``gid`` carries the global ids (visited-set
+        # bookkeeping) — a closure over ``self.store.data`` would also
+        # freeze whatever rows were resident at trace time.
         clients, server = state.clients, state.server
         hp, kappa = self.hp, server.kappa
 
@@ -385,12 +385,7 @@ class RWSADMMTrainer(TrainerBase):
         latency_s, energy_j = self._price(graph, i_k, idx, mask)
 
         key = markov.round_key(rng)
-        kwargs = {}
-        if self.store is not None:
-            state, zone_idx = self._ensure_round(state, idx)
-            kwargs = {"gid": jnp.asarray(idx), "data": self.store.data}
-        else:
-            zone_idx = idx
+        state, zone_idx, kwargs = self._round_plane(state, idx)
         args = [state, jnp.asarray(zone_idx), jnp.asarray(mask),
                 jnp.asarray(float(n_i)), key]
         if self._use_iw:
@@ -427,6 +422,17 @@ class RWSADMMTrainer(TrainerBase):
 
     def _with_clients(self, state, clients):
         return state._replace(clients=clients)
+
+    def _round_plane(self, state, idx):
+        """(state, zone ids, data kwargs) for one eager round: the lazy
+        plane makes the zone resident and hands the step store slots,
+        the global ids and the packed rows; the dense plane hands it the
+        global ids and the stacked dataset."""
+        if self.store is None:
+            return state, idx, {"data": self.data}
+        state, zone_idx = self._ensure_round(state, idx)
+        return state, zone_idx, {"gid": jnp.asarray(idx),
+                                 "data": self.store.data}
 
     def prefetch_chunk(self, sched) -> int:
         """Hand the NEXT chunk's working set to the store's async
@@ -551,21 +557,21 @@ class RWSADMMTrainer(TrainerBase):
             elif self._use_iw:
                 # Biased walk policy: the schedule's per-round importance
                 # weights ride along as one more scan input.
-                def chunk(state, idx, mask, n_i, keys, iws):
+                def chunk(state, data, idx, mask, n_i, keys, iws):
                     def body(carry, per_round):
                         i_r, m_r, ni_r, k_r, w_r = per_round
                         new_state, loss = round_fn(carry, i_r, m_r, ni_r,
-                                                   k_r, w_r)
+                                                   k_r, w_r, data=data)
                         return new_state, (loss, new_state.server.kappa)
 
                     return jax.lax.scan(
                         body, state, (idx, mask, n_i, keys, iws))
             else:
-                def chunk(state, idx, mask, n_i, keys):
+                def chunk(state, data, idx, mask, n_i, keys):
                     def body(carry, per_round):
                         i_r, m_r, ni_r, k_r = per_round
                         new_state, loss = round_fn(carry, i_r, m_r, ni_r,
-                                                   k_r)
+                                                   k_r, data=data)
                         return new_state, (loss, new_state.server.kappa)
 
                     return jax.lax.scan(
@@ -582,12 +588,11 @@ class RWSADMMTrainer(TrainerBase):
                 fn = jax.jit(chunk)
             self._chunk_fns[engine] = fn
 
-        args = []
         if lazy:
-            args += [self.store.data, jnp.asarray(slot_idx),
-                     jnp.asarray(sched.idx)]
+            args = [self.store.data, jnp.asarray(slot_idx),
+                    jnp.asarray(sched.idx)]
         else:
-            args.append(jnp.asarray(sched.idx))
+            args = [self.data, jnp.asarray(sched.idx)]
         args += [jnp.asarray(sched.mask), jnp.asarray(sched.n_i),
                  jnp.asarray(sched.keys)]
         if self._use_iw:

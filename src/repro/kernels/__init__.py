@@ -7,6 +7,7 @@
   * rglru_scan — blocked linear recurrence for RG-LRU / hybrid archs.
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit
-wrapper; interpret=True off-TPU), ref.py (pure-jnp oracle).
+wrapper; interpret mode on CPU only, see backend.py), ref.py (pure-jnp
+oracle).
 """
 from . import flash_decode, rglru_scan, rwsadmm_update  # noqa: F401

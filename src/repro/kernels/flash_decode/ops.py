@@ -6,11 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..backend import interpret_mode
 from . import kernel
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("window", "seq_block"))
@@ -26,5 +23,5 @@ def flash_decode(q, k, v, length, *, window: int | None = None,
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     return kernel.flash_decode_gqa(
         q, k, v, jnp.asarray(length, jnp.int32), window=window,
-        seq_block=sb, interpret=_interpret(),
+        seq_block=sb, interpret=interpret_mode(),
     )

@@ -27,14 +27,16 @@ def _kernel(a_ref, b_ref, h_ref, carry_scr, *, seq_block):
     def _init():
         carry_scr[...] = jnp.zeros_like(carry_scr)
 
+    # Rows stay 2-D (1, Db) slices: Mosaic keeps its (sublane, lane)
+    # layout for them, while a rank-1 row aborts the TPU compiler.
     def body(t, carry):
-        h = a_ref[0, t] * carry + b_ref[0, t]      # (Db,)
-        h_ref[0, t] = h
+        row = pl.ds(t, 1)
+        h = a_ref[0, row, :] * carry + b_ref[0, row, :]
+        h_ref[0, row, :] = h
         return h
 
-    carry = carry_scr[0]
-    carry = jax.lax.fori_loop(0, seq_block, body, carry)
-    carry_scr[0] = carry
+    carry = jax.lax.fori_loop(0, seq_block, body, carry_scr[...])
+    carry_scr[...] = carry
 
 
 def rglru_scan_blocked(a, b, *, seq_block: int = SEQ_BLOCK,

@@ -6,11 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..backend import interpret_mode
 from . import kernel
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("seq_block", "ch_block"))
@@ -27,5 +24,5 @@ def rglru_scan(a, b, *, seq_block: int | None = None,
         a = jnp.pad(a, ((0, 0), (0, pad_s), (0, pad_d)))
         b = jnp.pad(b, ((0, 0), (0, pad_s), (0, pad_d)))
     h = kernel.rglru_scan_blocked(a, b, seq_block=sb, ch_block=ch_block,
-                                  interpret=_interpret())
+                                  interpret=interpret_mode())
     return h[:, :s, :d]

@@ -13,6 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 8 * 1024  # elements per program: 7 arrays × 32 KB fp32 in VMEM
 
@@ -99,22 +100,24 @@ def _multizone_kernel(x_ref, z_ref, y_ref, g_ref, mask_ref, kappa_ref,
                       zone):
     """One walker's zone block (leading size-1 walker axis carved out by
     the grid) — same math as :func:`_zone_kernel` against that walker's
-    own token slice."""
-    y = y_ref[0]
+    own token slice. Rows stay 2-D ``(1, block)`` tiles; the walker's
+    mask row and κ are SMEM scalars."""
+    k = pl.program_id(0)
+    y = y_ref[0]                   # (1, block)
     kappa = kappa_ref[0]
     acc = jnp.zeros_like(y)
     for j in range(zone):          # static unroll over the padded zone
-        m = mask_ref[0, j]
-        x = x_ref[0, j]
-        z = z_ref[0, j]
-        g = g_ref[0, j]
+        m = mask_ref[k, j]
+        x = x_ref[0, j:j + 1]
+        z = z_ref[0, j:j + 1]
+        g = g_ref[0, j:j + 1]
         s_prev = jnp.sign(y - x)
         x_new = y - g / beta + s_prev * (z - beta * eps_half) / beta
         z_new = z + kappa * beta * (x_new - y - eps_half)
         c_old = x - (z / beta + eps_half) * s_prev
         c_new = x_new - (z_new / beta + eps_half) * jnp.sign(y - x_new)
-        x_out[0, j] = m * x_new + (1.0 - m) * x
-        z_out[0, j] = m * z_new + (1.0 - m) * z
+        x_out[0, j:j + 1] = m * x_new + (1.0 - m) * x
+        z_out[0, j:j + 1] = m * z_new + (1.0 - m) * z
         acc = acc + m * (c_new - c_old)
     y_out[0] = y + acc / n_total
 
@@ -136,23 +139,26 @@ def multizone_fused_update_flat(x, z, y, g, mask, kappa, *, beta: float,
     assert n % block == 0, (n, block)
     grid = (k_walkers, n // block)
     mspec = pl.BlockSpec((1, zone, block), lambda k, i: (k, 0, i))
-    yspec = pl.BlockSpec((1, block), lambda k, i: (k, i))
-    maskspec = pl.BlockSpec((1, zone), lambda k, i: (k, 0))
-    kspec = pl.BlockSpec((1,), lambda k, i: (0,))
+    # y rides as (K, 1, N): its (1, 1, block) tile keeps the second-minor
+    # block dim equal to the array's, which Mosaic accepts for every K
+    # (a (1, block) tile over (K, N) is refused unless 8 | K or K = 1).
+    yspec = pl.BlockSpec((1, 1, block), lambda k, i: (k, 0, i))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole mask / κ
     out_shape = [
         jax.ShapeDtypeStruct((k_walkers, zone, n), x.dtype),
         jax.ShapeDtypeStruct((k_walkers, zone, n), x.dtype),
-        jax.ShapeDtypeStruct((k_walkers, n), x.dtype),
+        jax.ShapeDtypeStruct((k_walkers, 1, n), x.dtype),
     ]
-    return pl.pallas_call(
+    x_new, z_new, y_new = pl.pallas_call(
         functools.partial(_multizone_kernel, beta=beta, eps_half=eps_half,
                           n_total=n_total, zone=zone),
         grid=grid,
-        in_specs=[mspec, mspec, yspec, mspec, maskspec, kspec],
+        in_specs=[mspec, mspec, yspec, mspec, smem, smem],
         out_specs=[mspec, mspec, yspec],
         out_shape=out_shape,
         interpret=interpret,
-    )(x, z, y, g, mask, kappa)
+    )(x, z, y[:, None], g, mask, kappa)
+    return x_new, z_new, y_new[:, 0]
 
 
 def zone_fused_update_flat(x, z, y, g, mask, kappa, *, beta: float,

@@ -1,8 +1,8 @@
 """jit wrapper: pytree-level fused RWSADMM update via the Pallas kernel.
 
 Flattens the parameter pytree once, pads to the block size, runs the
-fused kernel, and unflattens. On non-TPU backends the kernel executes in
-interpret mode (Python/CPU) for correctness validation.
+fused kernel, and unflattens. On TPU the kernel compiles through Mosaic;
+on CPU it runs in interpret mode for the tests (``kernels.backend``).
 """
 from __future__ import annotations
 
@@ -12,11 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from ...core import tree as tree_util
+from ..backend import interpret_mode
 from . import kernel
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("beta", "eps_half", "n_total",
@@ -35,7 +32,7 @@ def rwsadmm_fused_update(x, z, y, g, kappa, *, beta: float, eps_half: float,
     kappa_arr = jnp.reshape(jnp.asarray(kappa, xf.dtype), (1,))
     x_new, z_new, y_new = kernel.fused_update_flat(
         xf, zf, yf, gf, kappa_arr, beta=beta, eps_half=eps_half,
-        n_total=n_total, interpret=_interpret(), block=block,
+        n_total=n_total, interpret=interpret_mode(), block=block,
     )
     if pad:
         x_new, z_new, y_new = (a[:n] for a in (x_new, z_new, y_new))
@@ -69,7 +66,7 @@ def rwsadmm_zone_fused_update(x, z, y, g, mask, kappa, *, beta: float,
     mask_arr = jnp.asarray(mask, yf.dtype)
     x_new, z_new, y_new = kernel.zone_fused_update_flat(
         xf, zf, yf, gf, mask_arr, kappa_arr, beta=beta, eps_half=eps_half,
-        n_total=n_total, interpret=_interpret(), block=block,
+        n_total=n_total, interpret=interpret_mode(), block=block,
     )
     if pad:
         x_new, z_new = (a[:, :n] for a in (x_new, z_new))
@@ -107,7 +104,7 @@ def rwsadmm_multizone_fused_update(x, z, y, g, mask, kappa, *, beta: float,
     mask_arr = jnp.asarray(mask, yf.dtype)
     x_new, z_new, y_new = kernel.multizone_fused_update_flat(
         xf, zf, yf, gf, mask_arr, kappa_arr, beta=beta, eps_half=eps_half,
-        n_total=n_total, interpret=_interpret(), block=block,
+        n_total=n_total, interpret=interpret_mode(), block=block,
     )
     if pad:
         x_new, z_new = (a[..., :n] for a in (x_new, z_new))

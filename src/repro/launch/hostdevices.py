@@ -45,17 +45,33 @@ def ensure_host_platform_devices(count: int = 512) -> bool:
     return True
 
 
+def require_cpu_platform(env=None) -> None:
+    """Raise unless ``env`` (default: this process) already selects the
+    CPU backend with ``JAX_PLATFORMS=cpu``. Forced host devices are a
+    CPU rehearsal; nothing here switches an accelerator host to the CPU
+    on the caller's behalf. Reads only the environment, so it decides
+    before the backend initializes."""
+    env = os.environ if env is None else env
+    if env.get("JAX_PLATFORMS", "") != "cpu":
+        raise RuntimeError(
+            "forced host platform devices are a CPU rehearsal: set "
+            "JAX_PLATFORMS=cpu to run on N virtual CPU devices "
+            f"(JAX_PLATFORMS is {env.get('JAX_PLATFORMS')!r}); on an "
+            "accelerator host, run on its real devices instead")
+
+
 def host_device_env(count: int, base: dict | None = None,
                     *, tcmalloc: bool = True) -> dict:
     """Environment dict for a FRESH subprocess that should see ``count``
     host platform devices: XLA flag + (when available) the olmax
     tcmalloc LD_PRELOAD, which keeps many-device CPU allocation from
-    serializing on glibc malloc."""
+    serializing on glibc malloc. ``base`` must already ask for the CPU
+    backend (:func:`require_cpu_platform`)."""
     env = dict(os.environ if base is None else base)
+    require_cpu_platform(env)
     flags = env.get("XLA_FLAGS", "")
     if _FLAG not in flags:
         env["XLA_FLAGS"] = (f"--{_FLAG}={int(count)} " + flags).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
     if tcmalloc and "LD_PRELOAD" not in env:
         for p in _TCMALLOC_PATHS:
             if os.path.exists(p):

@@ -8,8 +8,10 @@ loop in ``jax.profiler.trace`` writing a TensorBoard-loadable trace to
 regions with :func:`annotate` (``jax.profiler.TraceAnnotation``) so the
 device timeline carries the same phase names as the event stream.
 
-Everything degrades to a no-op when profiling is off or the profiler is
-unavailable, so these hooks are safe to leave in library code.
+Both hooks cost nothing when profiling is off, so they are safe to leave
+in library code. When a run asked for a trace and the profiler cannot
+start, that is an error: a run that silently records no trace would be
+read as one that was measured.
 """
 from __future__ import annotations
 
@@ -32,26 +34,20 @@ def maybe_trace(run=None):
     if run is None or not profiling_enabled(run):
         yield None
         return
-    logdir = os.path.join(run.run_dir, "profile")
-    try:
-        import jax.profiler as jp
+    import jax.profiler as jp
 
-        os.makedirs(logdir, exist_ok=True)
-        with jp.trace(logdir):
-            yield logdir
-        run.update_manifest(profile_dir="profile")
-    except Exception:
-        # Profiler unavailable (or a second concurrent trace): never
-        # let observability take down the run being observed.
-        yield None
+    logdir = os.path.join(run.run_dir, "profile")
+    os.makedirs(logdir, exist_ok=True)
+    # No try/except around the yield: the body's exceptions propagate
+    # unchanged, and so does a profiler that fails to start.
+    with jp.trace(logdir):
+        yield logdir
+    run.update_manifest(profile_dir="profile")
 
 
 def annotate(name: str):
-    """Named region on the device trace (``TraceAnnotation``); a cheap
-    no-op context manager when the profiler is unavailable."""
-    try:
-        import jax.profiler as jp
+    """Named region on the device trace (``TraceAnnotation``); costs
+    next to nothing when no trace is being recorded."""
+    import jax.profiler as jp
 
-        return jp.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - jax always present in CI
-        return contextlib.nullcontext()
+    return jp.TraceAnnotation(name)

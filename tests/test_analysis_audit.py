@@ -40,7 +40,7 @@ def test_baked_constant_within_budget_ok():
 
 
 def test_float64_flagged():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         fn = jax.jit(lambda x: jnp.asarray(x, jnp.float64) * 2.0)
         rep = audit_closure("wide", fn, (jnp.ones((4,), jnp.float32),))
     assert "float64-op" in rules_of(rep)
@@ -90,7 +90,7 @@ def test_everything_broken_at_once():
         return s + x + big.sum()
 
     fn = jax.jit(f)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         rep = audit_closure(
             "broken", fn,
             (jnp.ones((4,), jnp.float64), jnp.ones((4,), jnp.float64)),

@@ -14,10 +14,9 @@ SCRIPT = textwrap.dedent("""
     import json, sys
     import repro.launch.dryrun as D
     import repro.launch.mesh as M
-    import jax
     M.make_production_mesh = (
-        lambda multi_pod=False: jax.make_mesh((2,2,2), ("pod","data","model"))
-        if multi_pod else jax.make_mesh((2,4), ("data","model")))
+        lambda multi_pod=False: M.make_mesh((2,2,2), ("pod","data","model"))
+        if multi_pod else M.make_mesh((2,4), ("data","model")))
     D.make_production_mesh = M.make_production_mesh
     import repro.configs.base as CB
     CB.INPUT_SHAPES["train_4k"] = CB.InputShape("train_4k", 256, 8, "train")

@@ -72,10 +72,9 @@ def test_server_token_is_deployable_checkpoint(fed_setup, tmp_path):
     path = str(tmp_path / "ckpt_30.npz")
     save_pytree(path, state.server.y)
     restored = load_pytree(path, state.server.y)
-    import jax.numpy as jnp
-
-    a1, _ = tr.eval_shared(state.server.y, jnp.arange(tr.n_clients))
-    a2, _ = tr.eval_shared(restored, jnp.arange(tr.n_clients))
+    cols = (data.x_test, data.y_test, data.mask_test)
+    a1, _ = tr.eval_rows_shared(state.server.y, *cols)
+    a2, _ = tr.eval_rows_shared(restored, *cols)
     np.testing.assert_allclose(a1, a2)
 
 

@@ -407,3 +407,35 @@ def test_lazy_store_counters_match_oracle(fed_lazy, fed, tmp_path):
             oracle.move_to_end(i)
         expect.append(d)
     assert got == expect
+
+
+# ------------------------------------------------------------ profiler ---
+@pytest.mark.parametrize("profile", [False, True])
+def test_maybe_trace_propagates_body_errors(tmp_path, profile):
+    """The traced loop's own exception reaches the caller unchanged,
+    with or without a trace being recorded."""
+    from repro.telemetry import maybe_trace
+
+    run = TelemetryRun(str(tmp_path / "run"), profile=profile)
+    with pytest.raises(ZeroDivisionError):
+        with maybe_trace(run):
+            1 / 0
+    run.close()
+
+
+def test_maybe_trace_fails_when_profiler_cannot_start(tmp_path):
+    """A run that asked for a trace does not silently record none: a
+    second concurrent trace is refused by the profiler, and that error
+    surfaces instead of a no-op."""
+    from repro.telemetry import maybe_trace
+
+    outer = TelemetryRun(str(tmp_path / "outer"), profile=True)
+    inner = TelemetryRun(str(tmp_path / "inner"), profile=True)
+    with maybe_trace(outer) as logdir:
+        assert logdir is not None
+        with pytest.raises(Exception, match="(?i)profil|trace"):
+            with maybe_trace(inner):
+                pass
+    outer.close()
+    inner.close()
+    assert outer.manifest.get("profile_dir") == "profile"
