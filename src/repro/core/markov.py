@@ -15,6 +15,7 @@ Implements the paper's §3:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 import weakref
@@ -679,6 +680,11 @@ def _plan_rounds(graphs, positions, zone_size, rng, avails):
     return idx, mask, n_i, seeds, active
 
 
+def _no_phase(name: str, **meta):
+    """The default ``phase`` of the schedule functions: times nothing."""
+    return contextlib.nullcontext()
+
+
 def zone_schedule(
     dyn_graph,
     walker: RandomWalkServer,
@@ -689,6 +695,7 @@ def zone_schedule(
     start_round: int = 0,
     price=None,
     batched_walk: bool = False,
+    phase=_no_phase,
 ) -> ZoneSchedule:
     """Precompute ``rounds`` zone rounds: graphs (covering regeneration
     epochs), random-walk positions, padded zone membership, and PRNG keys.
@@ -711,6 +718,11 @@ def zone_schedule(
     for the pre-drawn-uniform inverse-CDF sampler
     (:meth:`RandomWalkServer.walk_schedule_batched`) — an RNG-stream
     break from the eager driver, hence opt-in.
+
+    ``phase(name, **meta)`` returns a context manager that times one
+    step (``walk``; ``zones``: zone planning and the round keys;
+    ``price``): a telemetry phase span from the trainer, or nothing by
+    default.
     """
     first = start_round == 0
     graphs = dyn_graph.schedule(rounds, include_current=first)
@@ -718,21 +730,24 @@ def zone_schedule(
     avails = pop_trace() if pop_trace is not None else None
     step = (walker.walk_schedule_batched if batched_walk
             else walker.walk_schedule)
-    positions = step(graphs, advance_first=not first)
+    with phase("walk", rounds=rounds):
+        positions = step(graphs, advance_first=not first)
+        # The last `rounds` recorded weights align with `positions` in
+        # both advance_first regimes: with the round-0 convention the
+        # window's first entry is the walker's current position, whose
+        # weight was recorded when it was visited (1.0 at reset).
+        iw = walker.walk_weights(rounds)
 
-    # The last `rounds` recorded weights align with `positions` in both
-    # advance_first regimes: with the round-0 convention the window's
-    # first entry is the walker's current position, whose weight was
-    # recorded when it was visited (1.0 at reset).
-    iw = walker.walk_weights(rounds)
-
-    idx, mask, n_i, seeds, active = _plan_rounds(
-        graphs, positions, zone_size, rng, avails)
+    with phase("zones", rounds=rounds):
+        idx, mask, n_i, seeds, active = _plan_rounds(
+            graphs, positions, zone_size, rng, avails)
+        keys = round_keys(seeds)
     latency = energy = None
     if price is not None:
-        latency, energy = price(graphs, positions, idx, mask)
+        with phase("price", rounds=rounds):
+            latency, energy = price(graphs, positions, idx, mask)
     return ZoneSchedule(
-        idx=idx, mask=mask, n_i=n_i, keys=round_keys(seeds),
+        idx=idx, mask=mask, n_i=n_i, keys=keys,
         clients=positions.astype(np.int32), active=active,
         latency_s=latency, energy_j=energy, iw=iw,
     )
@@ -911,6 +926,7 @@ def fleet_zone_schedule(
     price_fleet=None,
     batched_walk: bool = False,
     fast_path: bool = True,
+    phase=_no_phase,
 ) -> FleetZoneSchedule:
     """Precompute ``rounds`` fleet rounds in one batched pass: the
     active-walker index, per-walker random-walk positions, the zone
@@ -937,6 +953,8 @@ def fleet_zone_schedule(
     ``price_fleet(graphs, clients (R, K), idx, mask) -> ((R, K), (R, K))``
     prices each walker's zone, aggregated to wall-clock (R,) columns
     (max latency — the zones are served in parallel — and summed energy).
+    ``phase`` times the ``walk``, ``zones`` and ``price`` steps as in
+    :func:`zone_schedule`.
     """
     k_walkers = len(walkers)
     first = start_round == 0
@@ -973,31 +991,35 @@ def fleet_zone_schedule(
         active_walker = ((start_round + rs) % k_walkers).astype(np.int32)
         positions = np.empty((rounds,), np.int64)
         iw = np.ones((rounds,), np.float64) if biased else None
-        for k, w in enumerate(walkers):
-            mine = np.flatnonzero(active_walker == k)
-            parked = mine[mine < lead]
-            if len(parked):
-                assert w.position is not None, "call reset() first"
-                positions[parked] = w.position
-                if iw is not None:
-                    # Parked rounds serve the walker's current position;
-                    # its weight was recorded at the visit that put it
-                    # there (1.0 for the reset visit) — same float the
-                    # eager fleet round reads.
-                    iw[parked] = w.weight_history[-1]
-            moving = mine[mine >= lead]
-            if len(moving):
-                positions[moving] = getattr(w, step_name)(
-                    [graphs[r] for r in moving], advance_first=True)
-                if iw is not None and w.is_biased:
-                    iw[moving] = w.walk_weights(len(moving))
-        idx, mask, n_i, seeds, active = _plan_rounds(
-            graphs, positions, zone_size, rng, avails)
+        with phase("walk", rounds=rounds):
+            for k, w in enumerate(walkers):
+                mine = np.flatnonzero(active_walker == k)
+                parked = mine[mine < lead]
+                if len(parked):
+                    assert w.position is not None, "call reset() first"
+                    positions[parked] = w.position
+                    if iw is not None:
+                        # Parked rounds serve the walker's current
+                        # position; its weight was recorded at the visit
+                        # that put it there (1.0 for the reset visit) —
+                        # same float the eager fleet round reads.
+                        iw[parked] = w.weight_history[-1]
+                moving = mine[mine >= lead]
+                if len(moving):
+                    positions[moving] = getattr(w, step_name)(
+                        [graphs[r] for r in moving], advance_first=True)
+                    if iw is not None and w.is_biased:
+                        iw[moving] = w.walk_weights(len(moving))
+        with phase("zones", rounds=rounds):
+            idx, mask, n_i, seeds, active = _plan_rounds(
+                graphs, positions, zone_size, rng, avails)
+            keys = round_keys(seeds)
         latency = energy = None
         if price is not None:
-            latency, energy = price(graphs, positions, idx, mask)
+            with phase("price", rounds=rounds):
+                latency, energy = price(graphs, positions, idx, mask)
         return FleetZoneSchedule(
-            idx=idx, mask=mask, n_i=n_i, keys=round_keys(seeds),
+            idx=idx, mask=mask, n_i=n_i, keys=keys,
             clients=positions.astype(np.int32), active=active,
             latency_s=latency, energy_j=energy, iw=iw,
             walker=active_walker,
@@ -1008,39 +1030,43 @@ def fleet_zone_schedule(
     # -- simultaneous -----------------------------------------------------
     positions = np.empty((rounds, k_walkers), np.int64)
     iw = np.ones((rounds, k_walkers), np.float64) if biased else None
-    for k, w in enumerate(walkers):
-        if lead:
-            assert w.position is not None, "call reset() first"
-            positions[0, k] = w.position
-            if iw is not None:
-                iw[0, k] = w.weight_history[-1]
-        if rounds > lead:
-            positions[lead:, k] = getattr(w, step_name)(
-                stepped, advance_first=True)
-            if iw is not None and w.is_biased:
-                iw[lead:, k] = w.walk_weights(rounds - lead)
+    with phase("walk", rounds=rounds):
+        for k, w in enumerate(walkers):
+            if lead:
+                assert w.position is not None, "call reset() first"
+                positions[0, k] = w.position
+                if iw is not None:
+                    iw[0, k] = w.weight_history[-1]
+            if rounds > lead:
+                positions[lead:, k] = getattr(w, step_name)(
+                    stepped, advance_first=True)
+                if iw is not None and w.is_biased:
+                    iw[lead:, k] = w.walk_weights(rounds - lead)
     z = zone_size
     idx = np.zeros((rounds, k_walkers, z), np.int32)
     mask = np.zeros((rounds, k_walkers, z), np.float32)
     n_i = np.zeros((rounds, k_walkers), np.float32)
     seeds = np.zeros((rounds,), np.int64)
-    for r in range(rounds):
-        av = None if avails is None else avails[r]
-        plan = (_plan_fleet_round_fast(graphs[r], positions[r], z, rng,
-                                       avail=av)
-                if fast_path else None)
-        if plan is None:        # overlapping neighborhoods this round
-            plan = plan_fleet_zone_round(graphs[r], positions[r], z,
-                                         rng, avail=av)
-        idx[r], mask[r], n_i[r] = plan
-        seeds[r] = round_key_seed(rng)
+    with phase("zones", rounds=rounds):
+        for r in range(rounds):
+            av = None if avails is None else avails[r]
+            plan = (_plan_fleet_round_fast(graphs[r], positions[r], z,
+                                           rng, avail=av)
+                    if fast_path else None)
+            if plan is None:    # overlapping neighborhoods this round
+                plan = plan_fleet_zone_round(graphs[r], positions[r], z,
+                                             rng, avail=av)
+            idx[r], mask[r], n_i[r] = plan
+            seeds[r] = round_key_seed(rng)
+        keys = round_keys(seeds)
     active = mask.sum(axis=2).astype(np.int32)          # (R, K)
     latency = energy = lat_kw = en_kw = None
     if price_fleet is not None:
-        lat_kw, en_kw = price_fleet(graphs, positions, idx, mask)
+        with phase("price", rounds=rounds):
+            lat_kw, en_kw = price_fleet(graphs, positions, idx, mask)
         latency, energy = lat_kw.max(axis=1), en_kw.sum(axis=1)
     return FleetZoneSchedule(
-        idx=idx, mask=mask, n_i=n_i, keys=round_keys(seeds),
+        idx=idx, mask=mask, n_i=n_i, keys=keys,
         clients=positions.astype(np.int32), active=active,
         latency_s=latency, energy_j=energy, iw=iw,
         sync=_sync_mask(start_round, rounds, sync_every),

@@ -206,34 +206,39 @@ class FleetRWSADMMTrainer(RWSADMMTrainer):
         clients = state.base.clients
         hp, kappa = self.hp, state.base.server.kappa
         k_walkers, zone = idx.shape
-        gather = lambda t: jax.tree_util.tree_map(lambda l: l[idx], t)
-        act = ClientState(x=gather(clients.x), z=gather(clients.z))
-        keys = jax.random.split(key, k_walkers * zone).reshape(
-            k_walkers, zone, -1)
+        # The named scopes of RWSADMMTrainer._round_impl.
+        with jax.named_scope("rwsadmm.zone_update"):
+            gather = lambda t: jax.tree_util.tree_map(lambda l: l[idx], t)
+            act = ClientState(x=gather(clients.x), z=gather(clients.z))
+        with jax.named_scope("rwsadmm.grad"):
+            keys = jax.random.split(key, k_walkers * zone).reshape(
+                k_walkers, zone, -1)
 
         def one_grad(params, client, kk):
             xb, yb = sample_batch(data, client, kk, self.batch_size)
             return self.value_and_grad_fn(params, xb, yb, kk)
 
-        losses, grads = jax.vmap(jax.vmap(one_grad))(act.x, idx, keys)
-        if use_fused:
-            # All K zones' Eq. 31 triple updates in ONE kernel launch.
-            x_f, z_f, y_new = fused_ops.rwsadmm_multizone_fused_update(
-                act.x, act.z, state.tokens, grads, mask, kappa,
-                beta=hp.beta, eps_half=hp.eps_half,
-                n_total=float(self.n_clients))
-            new_act = ClientState(x=x_f, z=z_f)
-        else:
-            new_act, y_new = rwsadmm.multizone_round_masked(
-                act, state.tokens, grads, mask, hp, kappa,
-                float(self.n_clients))
-        if iw is not None:
-            # Walk-for-Learning correction per walker: rescale each
-            # token's zone fold by its walker's importance weight.
-            y_new = jax.tree_util.tree_map(
-                lambda y0, y1: y0 + iw.reshape(
-                    (-1,) + (1,) * (y1.ndim - 1)) * (y1 - y0),
-                state.tokens, y_new)
+        with jax.named_scope("rwsadmm.grad"):
+            losses, grads = jax.vmap(jax.vmap(one_grad))(act.x, idx, keys)
+        with jax.named_scope("rwsadmm.zone_update"):
+            if use_fused:
+                # All K zones' Eq. 31 triple updates in ONE kernel launch.
+                x_f, z_f, y_new = fused_ops.rwsadmm_multizone_fused_update(
+                    act.x, act.z, state.tokens, grads, mask, kappa,
+                    beta=hp.beta, eps_half=hp.eps_half,
+                    n_total=float(self.n_clients))
+                new_act = ClientState(x=x_f, z=z_f)
+            else:
+                new_act, y_new = rwsadmm.multizone_round_masked(
+                    act, state.tokens, grads, mask, hp, kappa,
+                    float(self.n_clients))
+            if iw is not None:
+                # Walk-for-Learning correction per walker: rescale each
+                # token's zone fold by its walker's importance weight.
+                y_new = jax.tree_util.tree_map(
+                    lambda y0, y1: y0 + iw.reshape(
+                        (-1,) + (1,) * (y1.ndim - 1)) * (y1 - y0),
+                    state.tokens, y_new)
 
         # Scatter all K zones back in one add: the planner guarantees
         # the zones are disjoint, padded slots carry zero deltas.
@@ -246,16 +251,20 @@ class FleetRWSADMMTrainer(RWSADMMTrainer):
             mm = m_f.reshape((-1,) + (1,) * (fn.ndim - 1))
             return full.at[idx_f].add(mm * (fn - fo))
 
-        clients = ClientState(
-            x=jax.tree_util.tree_map(scatter, clients.x, act.x, new_act.x),
-            z=jax.tree_util.tree_map(scatter, clients.z, act.z, new_act.z))
+        with jax.named_scope("rwsadmm.scatter"):
+            clients = ClientState(
+                x=jax.tree_util.tree_map(scatter, clients.x, act.x,
+                                         new_act.x),
+                z=jax.tree_util.tree_map(scatter, clients.z, act.z,
+                                         new_act.z))
         tokens = _rendezvous(y_new, sync)
         server = ServerState(
             y=jax.tree_util.tree_map(lambda t: t[0], tokens),
             kappa=kappa * hp.kappa_decay,
             round=state.base.server.round + 1)
-        visited = state.base.visited.at[
-            idx_f if gid is None else gid.reshape(-1)].max(m_f > 0)
+        with jax.named_scope("rwsadmm.scatter"):
+            visited = state.base.visited.at[
+                idx_f if gid is None else gid.reshape(-1)].max(m_f > 0)
         loss = jnp.sum(losses * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         return FleetState(base=RWSADMMState(clients, server, visited),
                           tokens=tokens), loss
@@ -375,7 +384,7 @@ class FleetRWSADMMTrainer(RWSADMMTrainer):
             start_round=start_round, sync_every=self.sync_every,
             mode=self.fleet_mode, price=self._price_schedule,
             price_fleet=self._price_fleet_schedule,
-            batched_walk=self.batched_walk)
+            batched_walk=self.batched_walk, phase=self._phase)
 
     def run_chunk(self, state: FleetState, sched: FleetZoneSchedule,
                   engine: str = "scan"):

@@ -255,12 +255,17 @@ class RWSADMMTrainer(TrainerBase):
         # freeze whatever rows were resident at trace time.
         clients, server = state.clients, state.server
         hp, kappa = self.hp, server.kappa
+        # Named scopes split the round on a device trace; they change
+        # only the ops' op_name metadata, never the computation.
 
         # Gather active clients' ADMM variables: (Z, ...)
-        gather = lambda t: jax.tree_util.tree_map(lambda l: l[zone_idx], t)
-        act = ClientState(x=gather(clients.x), z=gather(clients.z))
+        with jax.named_scope("rwsadmm.zone_update"):
+            gather = lambda t: jax.tree_util.tree_map(
+                lambda l: l[zone_idx], t)
+            act = ClientState(x=gather(clients.x), z=gather(clients.z))
 
-        keys = jax.random.split(key, self.zone_size)
+        with jax.named_scope("rwsadmm.grad"):
+            keys = jax.random.split(key, self.zone_size)
         y_new = None   # set early by the fused kernel, late by the jnp fold
 
         if self.solver == "closed_form":
@@ -269,22 +274,24 @@ class RWSADMMTrainer(TrainerBase):
                 xb, yb = sample_batch(data, client, k, self.batch_size)
                 return self.value_and_grad_fn(params, xb, yb, k)
 
-            losses, grads = jax.vmap(one_grad)(act.x, zone_idx, keys)
-            if use_fused:
-                # Whole zone round (Eq. 31) in one HBM pass: x/z updates
-                # for every active client + the masked y fold.
-                x_f, z_f, y_new = fused_ops.rwsadmm_zone_fused_update(
-                    act.x, act.z, server.y, grads, zone_mask, kappa,
-                    beta=hp.beta, eps_half=hp.eps_half,
-                    n_total=float(self.n_clients),
-                )
-                new_act = ClientState(x=x_f, z=z_f)
-            else:
-                upd = jax.vmap(
-                    lambda c, g: rwsadmm.client_round(c, server.y, g, hp,
-                                                      kappa)
-                )
-                new_act, c_new, c_old = upd(act, grads)
+            with jax.named_scope("rwsadmm.grad"):
+                losses, grads = jax.vmap(one_grad)(act.x, zone_idx, keys)
+            with jax.named_scope("rwsadmm.zone_update"):
+                if use_fused:
+                    # Whole zone round (Eq. 31) in one HBM pass: x/z
+                    # updates for every active client + the masked y fold.
+                    x_f, z_f, y_new = fused_ops.rwsadmm_zone_fused_update(
+                        act.x, act.z, server.y, grads, zone_mask, kappa,
+                        beta=hp.beta, eps_half=hp.eps_half,
+                        n_total=float(self.n_clients),
+                    )
+                    new_act = ClientState(x=x_f, z=z_f)
+                else:
+                    upd = jax.vmap(
+                        lambda c, g: rwsadmm.client_round(c, server.y, g,
+                                                          hp, kappa)
+                    )
+                    new_act, c_new, c_old = upd(act, grads)
         else:
             # Iterative solver of the x-subproblem (Eq. 9): K stochastic
             # subgradient steps, warm-started at the client's stored x'.
@@ -301,11 +308,15 @@ class RWSADMMTrainer(TrainerBase):
                     )
                     return x, loss
 
-                kks = jax.random.split(k, self.inner_steps)
-                x_new, losses_ = jax.lax.scan(body, c.x, kks)
-                z_new = rwsadmm.z_update(x_new, server.y, c.z, hp, kappa)
-                c_old_ = rwsadmm.contribution(c.x, c.z, server.y, hp)
-                c_new_ = rwsadmm.contribution(x_new, z_new, server.y, hp)
+                with jax.named_scope("rwsadmm.grad"):
+                    kks = jax.random.split(k, self.inner_steps)
+                    x_new, losses_ = jax.lax.scan(body, c.x, kks)
+                with jax.named_scope("rwsadmm.zone_update"):
+                    z_new = rwsadmm.z_update(x_new, server.y, c.z, hp,
+                                             kappa)
+                    c_old_ = rwsadmm.contribution(c.x, c.z, server.y, hp)
+                    c_new_ = rwsadmm.contribution(x_new, z_new, server.y,
+                                                  hp)
                 return (ClientState(x=x_new, z=z_new), c_new_, c_old_,
                         losses_[-1])
 
@@ -318,39 +329,42 @@ class RWSADMMTrainer(TrainerBase):
         m = zone_mask  # (Z,)
         n_total = float(self.n_clients)
 
-        if y_new is None:
-            if self.dp_clip is not None:
-                # DP uploads: clip + noise each active client's Δc before
-                # it reaches the walking token (core/privacy.py).
-                from ..core import privacy
+        with jax.named_scope("rwsadmm.zone_update"):
+            if y_new is None:
+                if self.dp_clip is not None:
+                    # DP uploads: clip + noise each active client's Δc
+                    # before it reaches the walking token
+                    # (core/privacy.py).
+                    from ..core import privacy
 
-                dkeys = jax.random.split(jax.random.fold_in(key, 97),
-                                         self.zone_size)
-                deltas = jax.vmap(
-                    lambda k_, cn, co: privacy.privatize_delta(
-                        k_, cn, co, clip=self.dp_clip,
-                        noise_multiplier=self.dp_noise)
-                )(dkeys, c_new, c_old)
-            else:
-                deltas = jax.tree_util.tree_map(
-                    lambda cn, co: cn - co, c_new, c_old)
+                    dkeys = jax.random.split(jax.random.fold_in(key, 97),
+                                             self.zone_size)
+                    deltas = jax.vmap(
+                        lambda k_, cn, co: privacy.privatize_delta(
+                            k_, cn, co, clip=self.dp_clip,
+                            noise_multiplier=self.dp_noise)
+                    )(dkeys, c_new, c_old)
+                else:
+                    deltas = jax.tree_util.tree_map(
+                        lambda cn, co: cn - co, c_new, c_old)
 
-            def fold(y, d):
-                mm = m.reshape((-1,) + (1,) * (d.ndim - 1))
-                delta = jnp.sum(mm * d, axis=0) / n_total
-                # Importance-weight correction (biased walk policies):
-                # the zone fold is scaled by 1/(n π_{i_k}) so the
-                # y-update estimator stays unbiased under the biased
-                # visit distribution (docs/walks.md). iw=None (uniform
-                # policies) keeps the seed computation graph unchanged.
-                return y + (delta if iw is None else iw * delta)
+                def fold(y, d):
+                    mm = m.reshape((-1,) + (1,) * (d.ndim - 1))
+                    delta = jnp.sum(mm * d, axis=0) / n_total
+                    # Importance-weight correction (biased walk
+                    # policies): the zone fold is scaled by 1/(n π_{i_k})
+                    # so the y-update estimator stays unbiased under the
+                    # biased visit distribution (docs/walks.md). iw=None
+                    # (uniform policies) keeps the seed computation graph
+                    # unchanged.
+                    return y + (delta if iw is None else iw * delta)
 
-            y_new = jax.tree_util.tree_map(fold, server.y, deltas)
-        elif iw is not None:
-            # Fused-kernel path: the Pallas kernel already folded the
-            # unweighted zone delta into y; rescale it post hoc.
-            y_new = jax.tree_util.tree_map(
-                lambda y0, y1: y0 + iw * (y1 - y0), server.y, y_new)
+                y_new = jax.tree_util.tree_map(fold, server.y, deltas)
+            elif iw is not None:
+                # Fused-kernel path: the Pallas kernel already folded the
+                # unweighted zone delta into y; rescale it post hoc.
+                y_new = jax.tree_util.tree_map(
+                    lambda y0, y1: y0 + iw * (y1 - y0), server.y, y_new)
 
         # Scatter active deltas back (duplicate-free: zone indices unique,
         # padded slots masked to zero so .add is a no-op for them).
@@ -358,17 +372,21 @@ class RWSADMMTrainer(TrainerBase):
             mm = m.reshape((-1,) + (1,) * (new_act_.ndim - 1))
             return full.at[zone_idx].add(mm * (new_act_ - old_act))
 
-        clients = ClientState(
-            x=jax.tree_util.tree_map(scatter, clients.x, act.x, new_act.x),
-            z=jax.tree_util.tree_map(scatter, clients.z, act.z, new_act.z),
-        )
+        with jax.named_scope("rwsadmm.scatter"):
+            clients = ClientState(
+                x=jax.tree_util.tree_map(scatter, clients.x, act.x,
+                                         new_act.x),
+                z=jax.tree_util.tree_map(scatter, clients.z, act.z,
+                                         new_act.z),
+            )
         server = ServerState(
             y=y_new,
             kappa=server.kappa * hp.kappa_decay,
             round=server.round + 1,
         )
-        visited = state.visited.at[
-            zone_idx if gid is None else gid].max(m > 0)
+        with jax.named_scope("rwsadmm.scatter"):
+            visited = state.visited.at[
+                zone_idx if gid is None else gid].max(m > 0)
         zone_loss = jnp.sum(losses * m) / jnp.maximum(jnp.sum(m), 1.0)
         return RWSADMMState(clients, server, visited), zone_loss
 
@@ -456,7 +474,7 @@ class RWSADMMTrainer(TrainerBase):
         return markov.zone_schedule(
             self.dyn_graph, self.walker, rounds, self.zone_size, rng,
             start_round=start_round, price=self._price_schedule,
-            batched_walk=self.batched_walk,
+            batched_walk=self.batched_walk, phase=self._phase,
         )
 
     def chunk_is_cold(self, engine: str, rounds: int | None = None

@@ -27,8 +27,9 @@ between engines for the same trainer (asserted in
 ``telemetry=`` (a ``repro.telemetry.TelemetryRun``, default ``None``)
 records the run: manifest config, per-round ``round`` events, the
 walk/zone ``visit`` trace, eval ``snapshot`` events, and fenced
-``phase`` spans (``schedule`` / ``scan_chunk`` / ``eval`` /
-``round_eager``). Telemetry never touches an RNG stream or adds device
+``phase`` spans (``schedule`` / ``scan_chunk`` / ``readback`` /
+``eval`` / ``round_eager``), written out at the end of the call.
+Telemetry never touches an RNG stream or adds device
 syncs beyond the fences the drivers already imply, so telemetry-on
 trajectories are bit-identical to telemetry-off (pinned in
 ``tests/test_telemetry.py``). Render a recorded run with
@@ -111,6 +112,7 @@ def _finalize_telemetry(telemetry, result: SimulationResult) -> None:
     telemetry.counter("total_latency_s", result.total_latency_s)
     telemetry.counter("total_energy_j", result.total_energy_j)
     telemetry.counter("wall_time_s", round(result.wall_time_s, 6))
+    telemetry.flush()
 
 
 def run_simulation(
@@ -242,9 +244,11 @@ def _run_simulation_scan(
             # device→host sync per window): single-walker and fleet
             # schedules carry different columns (active walker, K zones,
             # per-walker pricing), so the schema lives with the trainer.
-            entries = [normalize_round_metrics(e, r + j) for j, e in
-                       enumerate(trainer.chunk_round_metrics(sched,
-                                                             stacked, r))]
+            with trainer._phase("readback", round=r,
+                                chunk_rounds=r_next - r):
+                entries = [normalize_round_metrics(e, r + j) for j, e in
+                           enumerate(trainer.chunk_round_metrics(
+                               sched, stacked, r))]
             for entry in entries:
                 total_comm += int(entry["comm_bytes"])
                 round_metrics.append(entry)

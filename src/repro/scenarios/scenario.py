@@ -41,6 +41,8 @@ waste for them.
 """
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 
 from ..core.graph import ClientGraph, detach_rollout_views
@@ -58,8 +60,9 @@ class Scenario:
         self.n = n
         self.cfg = cfg
         self.telemetry = telemetry   # TelemetryRun or None (off):
-        # schedule() emits fenced "scenario_rollout" phase spans into it
-        # — pure host-side control plane, no RNG or trajectory impact.
+        # schedule() emits "scenario_rollout" phase spans into it, with
+        # "mobility" and "links" spans inside — pure host-side control
+        # plane, no RNG or trajectory impact.
         self.positions_only = bool(positions_only)
         self.mobility = build_mobility(n, cfg.mobility,
                                        backend=cfg.graph_backend,
@@ -143,50 +146,55 @@ class Scenario:
         if include_current:
             graphs.append(self.current())
             avails.append(self.avail)
-        if self.telemetry is not None:
-            span = self.telemetry.phase(
-                "scenario_rollout", rounds=rounds, batched=bool(batched),
-                backend=self.cfg.graph_backend)
-            span.__enter__()
-        else:
-            span = None
-        if batched:
-            chunk = max(1, int(self.cfg.rollout_chunk))
-            while len(graphs) < rounds:
-                m = min(rounds - len(graphs), chunk)
-                base = self.mobility.rollout(m, self._rng_mob)
-                if self.link is not None:
-                    eff = self.link.apply_dropouts_batch(
-                        base, self._rng_link)
-                else:
-                    eff = base
-                if self.churn is not None:
-                    block = self.churn.rollout(
-                        self._round + 1, m, self._rng_churn)
-                    avails.extend(block)
-                    self.avail = block[-1]
-                self._round += m
-                graphs.extend(eff)
-                self._base = base[-1]
-                self.graph = eff[-1]
-        else:
-            while len(graphs) < rounds:
-                graphs.append(self.step())
-                avails.append(self.avail)
-        if span is not None:
-            span.__exit__(None, None, None)
-        # Copy-on-seed: the scenario retains the window's last graphs as
-        # its current state; their arrays/caches are views into the
-        # rollout's (R, n, n)/(R, n, 2) stacks and would pin the whole
-        # window in memory. Detach BEFORE mirroring positions so _pos
-        # references the copy, not the stack.
-        for g in (self._base, self.graph):
-            if g is not None:
-                detach_rollout_views(g)
-        self._pos = self._base.positions
-        self._avail_trace = (np.stack(avails)
-                             if self.churn is not None else None)
+        with self._phase("scenario_rollout", rounds=rounds,
+                         batched=bool(batched),
+                         backend=self.cfg.graph_backend):
+            if batched:
+                chunk = max(1, int(self.cfg.rollout_chunk))
+                while len(graphs) < rounds:
+                    m = min(rounds - len(graphs), chunk)
+                    with self._phase("mobility", rounds=m) as sp:
+                        base = self.mobility.rollout(m, self._rng_mob)
+                        if self.telemetry is not None:
+                            sp.meta["edges"] = _edges(base)
+                    if self.link is not None:
+                        with self._phase("links", rounds=m):
+                            eff = self.link.apply_dropouts_batch(
+                                base, self._rng_link)
+                    else:
+                        eff = base
+                    if self.churn is not None:
+                        block = self.churn.rollout(
+                            self._round + 1, m, self._rng_churn)
+                        avails.extend(block)
+                        self.avail = block[-1]
+                    self._round += m
+                    graphs.extend(eff)
+                    self._base = base[-1]
+                    self.graph = eff[-1]
+            else:
+                while len(graphs) < rounds:
+                    graphs.append(self.step())
+                    avails.append(self.avail)
+            # Copy-on-seed: the scenario retains the window's last graphs
+            # as its current state; their arrays/caches are views into
+            # the rollout's (R, n, n)/(R, n, 2) stacks and would pin the
+            # whole window in memory. Detach BEFORE mirroring positions
+            # so _pos references the copy, not the stack.
+            for g in (self._base, self.graph):
+                if g is not None:
+                    detach_rollout_views(g)
+            self._pos = self._base.positions
+            self._avail_trace = (np.stack(avails)
+                                 if self.churn is not None else None)
         return graphs
+
+    def _phase(self, name: str, **meta):
+        """A telemetry phase span, or an empty context when telemetry
+        is off."""
+        if self.telemetry is None:
+            return contextlib.nullcontext()
+        return self.telemetry.phase(name, **meta)
 
     def pop_avail_trace(self) -> np.ndarray | None:
         """(R, n) availability masks aligned with the last
@@ -235,6 +243,12 @@ class Scenario:
         (graph-free: works in positions-only mode)."""
         return self.comm.price_star_round(
             self._pos, members, payload_bytes)
+
+
+def _edges(graphs) -> int:
+    """Edges of the distinct graphs of a rollout (between regenerations
+    rounds share one graph object)."""
+    return sum(g.n_edges for g in {id(g): g for g in graphs}.values())
 
 
 def build_scenario(spec: ScenarioConfig | str | None, n: int,

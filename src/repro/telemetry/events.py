@@ -17,7 +17,8 @@ Event types
                ``latency_s``/``energy_j`` (CommModel columns).
 ``snapshot`` — one evaluation snapshot: ``round`` plus the eval dict
                (``acc``, ``acc_personalized``, ``comm_bytes_total``, …).
-``phase``    — one fenced phase-timer span: ``name``, ``seconds``;
+``phase``    — one fenced phase-timer span: ``name``, ``seconds``,
+               ``t0``, ``parent`` (the enclosing span's name or null);
                optionally ``round``, ``engine``, ``includes_compile``.
 ``counter``  — one named scalar: ``name``, ``value`` (totals, config
                echoes, benchmark readings).

@@ -4,9 +4,10 @@ Phase timers (``TelemetryRun.phase``) give wall-clock spans; when that
 is not enough, a run opened with ``profile=True`` (or with
 ``REPRO_PROFILE=1`` in the environment) additionally wraps its training
 loop in ``jax.profiler.trace`` writing a TensorBoard-loadable trace to
-``runs/<id>/profile/``, and hot-path call sites can annotate compiled
-regions with :func:`annotate` (``jax.profiler.TraceAnnotation``) so the
-device timeline carries the same phase names as the event stream.
+``runs/<id>/profile/``. Every phase span is an :func:`annotate` region
+(``jax.profiler.TraceAnnotation`` named ``repro.<phase>``), so the
+trace's host plane carries the same phase names as the event stream,
+on the device timeline's clock.
 
 Both hooks cost nothing when profiling is off, so they are safe to leave
 in library code. When a run asked for a trace and the profiler cannot

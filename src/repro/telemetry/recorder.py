@@ -7,15 +7,23 @@ default) holding:
   package versions, status; written atomically at open, on
   :meth:`update_manifest`, and at :meth:`close`.
 * ``events.jsonl``  — the typed event stream (``telemetry.events``),
-  one line per event, appended as the run executes.
+  one line per event. Events are kept in memory and appended in
+  batches: at :meth:`TelemetryRun.flush` (``run_simulation`` calls it
+  at the end of every call), whenever ``FLUSH_LINES`` are waiting, and
+  at :meth:`TelemetryRun.close`, so a timed loop pays no write per
+  event.
 * ``profile/``      — optional ``jax.profiler`` traces
   (``telemetry.profiler``, opt-in).
 
 Every layer of the stack emits into the same run: ``run_simulation``
 (rounds, snapshots, phase spans), the trainers' scan drivers (schedule
 precompute / chunk execution spans), ``Scenario.schedule`` (rollout
-spans), and the walk/zone trace stream (``telemetry.trace``). The
-recorder never touches an RNG and never forces a device sync the caller
+spans), and the walk/zone trace stream (``telemetry.trace``). Each
+phase span is also a ``jax.profiler.TraceAnnotation`` named
+``repro.<phase>``, so it lands on a profiler trace's host plane, on the
+device planes' clock, and records its ``parent``: the phase open around
+it on the same thread. The recorder never touches an RNG and never
+forces a device sync the caller
 didn't ask for (phase fencing is explicit via :meth:`PhaseSpan.fence`),
 so telemetry-on trajectories are bit-identical to telemetry-off — pinned
 in ``tests/test_telemetry.py``.
@@ -34,6 +42,7 @@ from typing import Any
 
 from . import events as ev
 from .artifacts import atomic_write_json
+from .profiler import annotate
 
 log = logging.getLogger("repro.telemetry")
 
@@ -43,6 +52,8 @@ log = logging.getLogger("repro.telemetry")
 DETERMINISTIC_MANIFEST_KEYS = (
     "schema_version", "seed", "config", "git_sha", "jax", "packages",
 )
+#: buffered event lines that trigger a write to ``events.jsonl``
+FLUSH_LINES = 4096
 
 
 def _git_sha() -> str | None:
@@ -105,6 +116,11 @@ class PhaseSpan:
     completed device work, not enqueue time. The fence is explicit
     (never implicit) so a span can also time pure host work without
     forcing a sync.
+
+    While open, the span is a ``repro.<name>`` profiler annotation and
+    the top of its thread's stack of open spans; its event records the
+    span below it on that stack as ``parent`` (``None`` at the top), so
+    a reader can work out self time.
     """
 
     def __init__(self, run: "TelemetryRun", name: str, meta: dict):
@@ -121,11 +137,18 @@ class PhaseSpan:
         return jax.block_until_ready(value)
 
     def __enter__(self) -> "PhaseSpan":
+        stack = self._run._open_spans()
+        self.parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self._note = annotate("repro." + self.name)
+        self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.seconds = time.perf_counter() - self._t0
+        self._note.__exit__(exc_type, exc, tb)
+        self._run._open_spans().pop()
         if exc_type is None:
             # ``t0`` (seconds since the run opened) lets the report CLI
             # reconstruct the span timeline — e.g. show the prefetch
@@ -135,14 +158,19 @@ class PhaseSpan:
             self._run.emit("phase", name=self.name,
                            seconds=self.seconds,
                            t0=round(self._t0 - self._run._t_open, 6),
-                           **self.meta)
+                           parent=self.parent, **self.meta)
 
 
 class _NullSpan(PhaseSpan):
-    """Phase span with no recorder attached (telemetry disabled)."""
+    """Phase span with no recorder attached (telemetry disabled): no
+    annotation, no stack, no event."""
 
     def __init__(self):  # noqa: D401 - trivial
         super().__init__(None, "", {})  # type: ignore[arg-type]
+
+    def __enter__(self) -> "PhaseSpan":
+        self._t0 = time.perf_counter()
+        return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.seconds = time.perf_counter() - self._t0
@@ -183,11 +211,14 @@ class TelemetryRun:
         self.events_path = os.path.join(run_dir, "events.jsonl")
         self.manifest_path = os.path.join(run_dir, "manifest.json")
         os.makedirs(run_dir, exist_ok=True)
-        self._fh = open(self.events_path, "a", buffering=1)
+        self._fh = open(self.events_path, "a")
+        self._lines: list[str] = []
         # Serializes appends: the lazy plane's prefetch worker emits its
         # staging phase span from a background thread while the main
         # thread streams round events.
         self._emit_lock = threading.Lock()
+        # Each thread's stack of open phase names (PhaseSpan.parent).
+        self._local = threading.local()
         self._counts: dict[str, int] = {}
         self._t_open = time.perf_counter()
         jx, pkgs = _environment()
@@ -224,14 +255,37 @@ class TelemetryRun:
 
     # -- event stream -----------------------------------------------------
     def emit(self, etype: str, **fields) -> None:
-        """Append one typed event to ``events.jsonl``."""
+        """Queue one typed event for ``events.jsonl`` (written at the
+        next :meth:`flush`, or now if ``FLUSH_LINES`` are waiting)."""
         if self._fh.closed:
             raise ev.TelemetryError(
                 f"telemetry run {self.run_id!r} is closed")
         line = ev.encode_event({"t": etype, **fields})
         with self._emit_lock:
-            self._fh.write(line + "\n")
+            self._lines.append(line)
             self._counts[etype] = self._counts.get(etype, 0) + 1
+            if len(self._lines) >= FLUSH_LINES:
+                self._write_lines()
+
+    def _write_lines(self) -> None:
+        """Append the queued lines (caller holds ``_emit_lock``)."""
+        if self._lines:
+            self._fh.write("\n".join(self._lines) + "\n")
+            self._lines.clear()
+        self._fh.flush()
+
+    def flush(self) -> None:
+        """Write every queued event to ``events.jsonl``."""
+        with self._emit_lock:
+            if not self._fh.closed:
+                self._write_lines()
+
+    def _open_spans(self) -> list[str]:
+        """This thread's stack of open phase names."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
     def round(self, metrics: dict) -> None:
         """One training round's ``round_metrics`` entry."""
@@ -265,14 +319,15 @@ class TelemetryRun:
     # -- lifecycle --------------------------------------------------------
     def close(self, **fields) -> None:
         """Finalize: flush events, stamp status/wall time/event counts."""
-        if not self._fh.closed:
-            self._fh.flush()
-            self._fh.close()
-        self.update_manifest(
-            status="finalized",
-            wall_time_s=round(time.perf_counter() - self._t_open, 6),
-            event_counts=dict(sorted(self._counts.items())),
-            **fields)
+        with self._emit_lock:
+            if not self._fh.closed:
+                self._write_lines()
+                self._fh.close()
+        self.update_manifest(**{
+            "status": "finalized",
+            "wall_time_s": round(time.perf_counter() - self._t_open, 6),
+            "event_counts": dict(sorted(self._counts.items())),
+            **fields})
 
     def __enter__(self) -> "TelemetryRun":
         return self

@@ -439,3 +439,142 @@ def test_maybe_trace_fails_when_profiler_cannot_start(tmp_path):
     outer.close()
     inner.close()
     assert outer.manifest.get("profile_dir") == "profile"
+
+
+# ------------------------------------------- span tree and annotations --
+SCHEDULE_PARENTS = {
+    "schedule": None, "scenario_rollout": "schedule",
+    "mobility": "scenario_rollout", "links": "scenario_rollout",
+    "walk": "schedule", "zones": "schedule", "price": "schedule",
+    "scan_chunk": None, "readback": None, "eval": None,
+    "init_state": None,
+}
+
+
+@pytest.mark.parametrize("fleet", [0, 3])
+def test_schedule_spans_record_their_parent(fed, tmp_path, fleet):
+    """A scan run (single walker and K = 3 fleet) emits a span for every
+    layer of ``schedule`` and the chunk's readback, each with the name
+    of the span open around it."""
+    with TelemetryRun(str(tmp_path / "run"), seed=0) as tel:
+        _run(fed, engine="scan", backend="sparse", fleet=fleet,
+             telemetry=tel)
+    phases = [e for e in read_events(tel.events_path) if e["t"] == "phase"]
+    got = {}
+    for e in phases:
+        assert got.setdefault(e["name"], e["parent"]) == e["parent"], e
+    assert got == SCHEDULE_PARENTS
+    # one schedule and one readback per chunk (8 rounds, eval every 4)
+    assert sum(e["name"] == "schedule" for e in phases) == 2
+    assert sum(e["name"] == "readback" for e in phases) == 2
+    mob = [e for e in phases if e["name"] == "mobility"]
+    assert all(e["edges"] > 0 for e in mob)
+    # round 0 (single walker) or the first K rounds (round-robin fleet)
+    # serve the current graph; the others are rolled out
+    assert sum(e["rounds"] for e in mob) == 8 - max(fleet, 1)
+
+
+def test_telemetry_off_opens_no_annotation(fed, tmp_path, monkeypatch):
+    """With telemetry off no span annotates or stacks anything, and the
+    trajectory stays bit-identical to a recorded run."""
+    from repro.telemetry import recorder
+
+    def refuse(name):
+        raise AssertionError(f"annotation {name!r} with telemetry off")
+
+    monkeypatch.setattr(recorder, "annotate", refuse)
+    res_off = _run(fed, engine="scan", backend="dense", fleet=3)
+    monkeypatch.undo()
+    with TelemetryRun(str(tmp_path / "run"), seed=0) as tel:
+        res_on = _run(fed, engine="scan", backend="dense", fleet=3,
+                      telemetry=tel)
+    assert res_off.round_metrics == res_on.round_metrics
+    assert res_off.history == res_on.history
+
+
+def test_parent_stack_is_per_thread(tmp_path):
+    """A span opened on another thread while one is open on the main
+    thread has no parent (the prefetch worker's staging span)."""
+    import threading
+
+    with TelemetryRun(str(tmp_path / "run")) as tel:
+        with tel.phase("outer"):
+            t = threading.Thread(target=lambda: tel.phase("worker")
+                                 .__enter__().__exit__(None, None, None))
+            t.start()
+            t.join(timeout=30)
+            assert not t.is_alive()
+            with tel.phase("inner"):
+                pass
+    got = {e["name"]: e["parent"] for e in read_events(tel.events_path)}
+    assert got == {"outer": None, "worker": None, "inner": "outer"}
+
+
+def test_profiler_trace_nests_program_spans(fed, tmp_path):
+    """On a CPU profiler trace of a tiny scan run, every program span is
+    a ``repro.<phase>`` host event that lies inside its parent's."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = _make_trainer(fed, "dense")
+    run_simulation(tr, rounds=4, eval_every=4, seed=0, engine="scan")
+    d = str(tmp_path / "prof")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with TelemetryRun(str(tmp_path / "run"), seed=0) as tel:
+        jax.profiler.start_trace(d, profiler_options=opts)
+        try:
+            run_simulation(tr, rounds=8, eval_every=4, seed=1,
+                           engine="scan", telemetry=tel)
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro."):
+                    spans.setdefault(e.name[6:], []).append(
+                        (line.name, e.start_ns, e.start_ns + e.duration_ns))
+    for name in ("schedule", "mobility", "links", "zones", "readback"):
+        assert spans.get(name), sorted(spans)
+    assert len(spans["schedule"]) == 2
+    for name, parent in SCHEDULE_PARENTS.items():
+        for line, s, e in spans.get(name, []) if parent else []:
+            assert any(pl == line and ps <= s and e <= pe
+                       for pl, ps, pe in spans[parent]), (name, parent)
+
+
+# ------------------------------------------------ batched writes --------
+def _lines(tel):
+    with open(tel.events_path) as f:
+        return f.read().splitlines()
+
+
+def test_events_are_written_in_batches(fed, tmp_path, monkeypatch):
+    """Events wait in memory until a flush: the end of each
+    run_simulation call, a full buffer, or close (also on failure)."""
+    from repro.telemetry import recorder
+
+    tel = TelemetryRun(str(tmp_path / "run"), seed=0)
+    tel.counter("a", 1)
+    assert _lines(tel) == []
+    _run(fed, engine="scan", backend="dense", rounds=4, telemetry=tel)
+    n = len(_lines(tel))
+    assert n == sum(tel._counts.values()) and n > 1
+    monkeypatch.setattr(recorder, "FLUSH_LINES", 3)
+    for i in range(5):
+        tel.counter("b", i)
+    assert len(_lines(tel)) == n + 3
+    tel.close()
+    assert len(_lines(tel)) == n + 5
+
+    with pytest.raises(ZeroDivisionError):
+        with TelemetryRun(str(tmp_path / "failed")) as bad:
+            bad.counter("c", 1)
+            1 / 0
+    assert [json.loads(x)["name"] for x in _lines(bad)] == ["c"]
+    assert bad.manifest["status"] == "failed"
