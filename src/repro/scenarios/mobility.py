@@ -59,6 +59,10 @@ GRAPH_BACKENDS = ("dense", "sparse")
 
 
 class MobilityModel(Protocol):
+    # Min-degree floor counters of the last sparse ``rollout``
+    # (``deficient``, ``ring_fallbacks``; empty on the dense backend).
+    floor_counts: dict[str, int]
+
     def reset(self, rng: np.random.Generator) -> ClientGraph: ...
 
     def step(self, rng: np.random.Generator) -> ClientGraph: ...
@@ -126,13 +130,20 @@ def range_graphs_batch(pos: np.ndarray, radio_range: float,
 # The dense lane's O(n²) distance matrix is what blocks large n. The
 # sparse lane buckets positions into a uniform grid of cells no smaller
 # than the search radius, so every within-radius pair lives in a 3×3
-# cell neighborhood: candidate generation is O(n · local density), and
-# the resulting graphs are capped-degree neighbor lists — O(n·k) end to
-# end. Where the construction is RNG-free (it is: graphs are a
-# deterministic function of positions) the sparse graphs are pinned
-# bit-identical to the dense lane at small n
-# (``tests/test_sparse_backend.py``).
+# cell neighborhood: candidate generation is O(n · local density) (each
+# unordered pair measured once, from half that neighborhood), and the
+# resulting graphs are capped-degree neighbor lists — O(n·k) end to
+# end. Every step is a whole-array pass; only the rare node whose k
+# nearest lie beyond a 5×5 cell block takes a per-node ring search.
+# Where the construction is RNG-free (it is: graphs are a deterministic
+# function of positions) the sparse graphs are pinned bit-identical to
+# the dense lane at small n (``tests/test_sparse_backend.py``).
 # ---------------------------------------------------------------------------
+
+
+# Cell offsets that, with the node's own cell (pairs in cell order),
+# reach every unordered pair of the 3×3 neighbourhood exactly once.
+_FORWARD_CELLS = ((1, -1), (1, 0), (1, 1), (0, 1))
 
 
 class _CellGrid:
@@ -146,43 +157,54 @@ class _CellGrid:
                           0, self.nc - 1)
         self.cy = np.clip((pos[:, 1] * self.nc).astype(np.int64),
                           0, self.nc - 1)
-        cid = self.cx * self.nc + self.cy
-        self.order = np.argsort(cid, kind="stable")
-        self._sorted_cid = cid[self.order]
+        self.cid = self.cx * self.nc + self.cy
+        self.order = np.argsort(self.cid, kind="stable")
+        self.count = np.bincount(self.cid, minlength=self.nc * self.nc)
+        self.start = np.cumsum(self.count) - self.count
 
     def _cell_bounds(self, cids: np.ndarray):
-        starts = np.searchsorted(self._sorted_cid, cids)
-        ends = np.searchsorted(self._sorted_cid, cids, side="right")
-        return starts, ends
+        starts = self.start[cids]
+        return starts, starts + self.count[cids]
+
+    def _offset_cells(self, cx, cy, dx, dy):
+        """(start, count) of the cells at offsets (dx, dy) from cells
+        (cx, cy), broadcast; cells off the grid count 0."""
+        nx, ny = cx + dx, cy + dy
+        ok = (nx >= 0) & (nx < self.nc) & (ny >= 0) & (ny < self.nc)
+        cid = np.where(ok, nx * self.nc + ny, 0)
+        return self.start[cid], np.where(ok, self.count[cid], 0)
+
+    def _check_search(self, max_pairs: int) -> None:
+        """Raise when the 3×3 search would generate more than
+        ``max_pairs`` directed candidates (self pairs included) — the
+        signal that the radio range is far too large for the node
+        density (the sparse backend expects a local graph; shrink
+        ``radio_range`` or use the dense lane)."""
+        nc = self.nc
+        c = self.count.reshape(nc, nc)
+        p = np.pad(c, 1)
+        box = sum(p[1 + dx:1 + dx + nc, 1 + dy:1 + dy + nc]
+                  for dx in (-1, 0, 1) for dy in (-1, 0, 1))
+        if int((c * box).sum()) > max_pairs:
+            raise ValueError(
+                f"cell-list search would generate > {max_pairs} "
+                "candidate pairs — the search radius is too "
+                "large for n (the graph is effectively dense). "
+                "Reduce radio_range (or min_degree) for the "
+                "sparse backend, or use graph_backend='dense'.")
 
     def candidate_pairs(self, max_pairs: int = 60_000_000
                         ) -> tuple[np.ndarray, np.ndarray]:
         """Directed candidate pairs (i, j), i ≠ j, over every node's 3×3
-        cell neighborhood (symmetric by construction). Raises when the
-        candidate count explodes — the signal that the radio range is
-        far too large for the node density (the sparse backend expects a
-        local graph; shrink ``radio_range`` or use the dense lane)."""
+        cell neighborhood (symmetric by construction). Raises past
+        ``max_pairs`` candidates (:meth:`_check_search`)."""
+        self._check_search(max_pairs)
         n = self.pos.shape[0]
-        nc = self.nc
         pis, pjs = [], []
-        total = 0
         for dx in (-1, 0, 1):
             for dy in (-1, 0, 1):
-                nx, ny = self.cx + dx, self.cy + dy
-                ok = (nx >= 0) & (nx < nc) & (ny >= 0) & (ny < nc)
-                ncid = np.where(ok, nx * nc + ny, 0)
-                starts, ends = self._cell_bounds(ncid)
-                cnt = np.where(ok, ends - starts, 0)
-                block = int(cnt.sum())
-                total += block
-                if total > max_pairs:
-                    raise ValueError(
-                        f"cell-list search would generate > {max_pairs} "
-                        "candidate pairs — the search radius is too "
-                        "large for n (the graph is effectively dense). "
-                        "Reduce radio_range (or min_degree) for the "
-                        "sparse backend, or use graph_backend='dense'.")
-                if not block:
+                starts, cnt = self._offset_cells(self.cx, self.cy, dx, dy)
+                if not cnt.any():
                     continue
                 pi = np.repeat(np.arange(n), cnt)
                 within = segmented_arange(cnt)
@@ -194,6 +216,70 @@ class _CellGrid:
             e = np.zeros(0, dtype=np.int64)
             return e, e.copy()
         return np.concatenate(pis), np.concatenate(pjs)
+
+    def range_pairs(self, r2: float, max_pairs: int = 60_000_000):
+        """Directed pairs (i, j) with squared distance ≤ ``r2``, both
+        orientations, sorted by (i, j), with their distances — the
+        in-range subset of :meth:`candidate_pairs`. Each unordered pair
+        is measured once (the own cell's pairs in cell order, plus the
+        four forward cells) and mirrored: (a − b)² = (b − a)² in IEEE
+        arithmetic, so both orientations carry the distance a directed
+        search computes. ``max_pairs`` bounds the 3×3 search's
+        directed candidates, as in :meth:`candidate_pairs`."""
+        self._check_search(max_pairs)
+        n = self.pos.shape[0]
+        # Work in cell order (slot s holds node order[s]): each slot
+        # pairs with the later slots of its own cell and with every slot
+        # of the four forward cells, and the gathers stay local.
+        cid = self.cid[self.order]
+        slot = np.arange(n)
+        starts = [slot + 1]
+        counts = [self.start[cid] + self.count[cid] - slot - 1]
+        for dx, dy in _FORWARD_CELLS:
+            st, cnt = self._offset_cells(cid // self.nc, cid % self.nc,
+                                         dx, dy)
+            starts.append(st)
+            counts.append(cnt)
+        cnt = np.concatenate(counts)
+        a = np.repeat(np.tile(slot, len(counts)), cnt)
+        b = np.arange(len(a)) + np.repeat(
+            np.concatenate(starts) - (np.cumsum(cnt) - cnt), cnt)
+        d2 = pair_sq_dists(self.pos[self.order], a, b)
+        keep = np.flatnonzero(d2 <= r2)
+        a, b, d2 = self.order[a[keep]], self.order[b[keep]], d2[keep]
+        pi = np.concatenate([a, b])
+        pj = np.concatenate([b, a])
+        by_key = np.argsort(pi * n + pj)
+        return pi[by_key], pj[by_key], np.concatenate([d2, d2])[by_key]
+
+    def block_knn(self, rows: np.ndarray, k: int, reach: int = 2):
+        """The k nearest other nodes of each of ``rows`` among the
+        (2·reach + 1)² cells around its own, nearest first, as an
+        (m, k) array (-1 where the block holds fewer), and whether that
+        is the exact answer: the k-th is nearer than ``reach · side``,
+        which no node outside the block can be."""
+        m = len(rows)
+        span = np.arange(-reach, reach + 1)
+        st, cnt = self._offset_cells(
+            self.cx[rows, None], self.cy[rows, None],
+            np.repeat(span, len(span)), np.tile(span, len(span)))
+        cnt = cnt.ravel()
+        owner = np.repeat(np.repeat(np.arange(m), len(span) ** 2), cnt)
+        cand = self.order[np.repeat(st.ravel(), cnt)
+                          + segmented_arange(cnt)]
+        keep = cand != rows[owner]
+        owner, cand = owner[keep], cand[keep]
+        d2 = pair_sq_dists(self.pos, rows[owner], cand)
+        by_dist = np.lexsort((d2, owner))
+        owner, cand, d2 = owner[by_dist], cand[by_dist], d2[by_dist]
+        rank = segmented_arange(np.bincount(owner, minlength=m))
+        knn = np.full((m, k), -1, dtype=np.int64)
+        near = rank < k
+        knn[owner[near], rank[near]] = cand[near]
+        kth = np.full(m, np.inf)
+        last = rank == k - 1
+        kth[owner[last]] = d2[last]
+        return knn, kth < (reach * self.side) ** 2
 
     def ring_nodes(self, i: int, r: int) -> np.ndarray:
         """Nodes in cells at Chebyshev cell-distance exactly ``r`` from
@@ -249,76 +335,151 @@ class _CellGrid:
 def _cap_degree_pairs(n: int, pi, pj, d2, k_max: int):
     """Truncate per-node degree to the ``k_max`` nearest, then drop the
     asymmetric leftovers (an edge survives only if both endpoints keep
-    it) so the graph stays undirected. Returns (i, j)-sorted pairs."""
-    order = np.lexsort((pj, pi))
-    pi, pj, d2 = pi[order], pj[order], d2[order]
+    it) so the graph stays undirected. Takes and returns (i, j)-sorted
+    pairs."""
     deg = np.bincount(pi, minlength=n)
-    if not len(pi) or deg.max() <= k_max:
+    over = deg > k_max
+    if not over.any():
         return pi, pj, d2
-    by_dist = np.lexsort((d2, pi))
-    rank = np.empty(len(pi), dtype=np.int64)
-    rank[by_dist] = segmented_arange(deg)
-    keep_dir = rank < k_max
-    key = pi * n + pj
-    ridx = np.searchsorted(key, pj * n + pi)
-    keep = keep_dir & keep_dir[ridx]
+    # Only rows over the cap rank their links; a link they drop goes in
+    # both orientations.
+    sel = np.flatnonzero(over[pi])
+    by_dist = np.lexsort((d2[sel], pi[sel]))
+    rank = np.empty(len(sel), dtype=np.int64)
+    rank[by_dist] = segmented_arange(deg[over])
+    drop = sel[rank >= k_max]
+    keep = np.ones(len(pi), dtype=bool)
+    keep[drop] = False
+    keep[np.searchsorted(pi * n + pj, pj[drop] * n + pi[drop])] = False
     return pi[keep], pj[keep], d2[keep]
 
 
-def _patch_min_degree_lists(nbrs, mask, nd2, pos, grid: _CellGrid,
-                            k: int):
-    """Link each below-floor node to its exact k nearest neighbors
-    (expanding-ring search; deficient rows only — the same semantics as
-    the dense lane's argpartition patch). Returns (nbrs, mask, nd2)."""
-    if k <= 0:
-        return nbrs, mask, nd2
-    from ..core.graph import _insert_edge_lists
+def _floor_attempts(n, key, deg, rows, knn):
+    """The min-degree floor as row-by-row insertion attempts: for each
+    deficient row i in ascending order and each of its k nearest j,
+    (i, j) then (j, i). Returns the attempts' (u, v), whether each adds
+    an edge (absent from the sorted pair keys ``key`` and not attempted
+    before), and each attempted row's degree just before it."""
+    k = knn.shape[1]
+    ii = np.repeat(rows, k)
+    jj = knn.ravel()
+    u = np.stack([ii, jj], axis=1).ravel()
+    v = np.stack([jj, ii], axis=1).ravel()
+    att = u * n + v
+    at = np.minimum(np.searchsorted(key, att), max(len(key) - 1, 0))
+    present = (key[at] == att) if len(key) else np.zeros(len(att), bool)
+    first = np.zeros(len(att), dtype=bool)
+    first[np.unique(att, return_index=True)[1]] = True
+    new = first & ~present
+    by_row = np.argsort(u, kind="stable")
+    added = new[by_row]
+    before = np.cumsum(added) - added
+    head = np.r_[True, u[by_row][1:] != u[by_row][:-1]]
+    group = np.maximum.accumulate(np.where(head, np.arange(len(u)), 0))
+    d = np.empty(len(u), dtype=np.int64)
+    d[by_row] = deg[u[by_row]] + before - before[group]
+    return u, v, new, d
 
-    deg = mask.sum(axis=1)
-    for i in np.flatnonzero(deg < k):
-        for j in grid.exact_knn(int(i), k):
-            e2 = float(pair_sq_dists(pos, np.asarray([i]),
-                                     np.asarray([j]))[0])
-            nbrs, mask, nd2 = _insert_edge_lists(
-                nbrs, mask, nd2, int(i), int(j), e2)
-    return nbrs, mask, nd2
+
+def _grown_width(d, width: int) -> int:
+    """The packed width row-by-row insertion ends at: before each
+    attempt ``_insert_edge_lists`` grows a full row (degree = width,
+    the edge present or not) by max(4, width // 2)."""
+    t = 0
+    while True:
+        hit = np.flatnonzero(d[t:] == width)
+        if not len(hit):
+            return width
+        t += int(hit[0]) + 1
+        width += max(4, width // 2)
+
+
+def _floor_min_degree(n, pi, pj, d2, pos, grid: _CellGrid, k: int,
+                      counts: dict | None = None):
+    """Pack (i, j)-sorted pairs into neighbor lists under the min-degree
+    floor: each row below ``k`` (before patching) is linked to its exact
+    k nearest neighbors — the dense lane's argpartition patch. One
+    whole-array pass: the k nearest of every deficient row from its 5×5
+    cell block, the expanding-ring search only for rows the block cannot
+    settle; the union taken and packed once. The packed width is the one
+    row-by-row insertion reaches (:func:`_grown_width`); where a row's
+    own insertions could fill its row with an edge already present, the
+    order of its k nearest decides that width, so such rows take the
+    ring search's order too. ``counts`` (optional) accumulates
+    ``deficient`` rows and the ``ring_fallbacks`` among them. Returns
+    (nbrs, mask, nd2)."""
+    deg = np.bincount(pi, minlength=n)
+    width = max(1, int(deg.max()) if n else 1)
+    rows = np.flatnonzero(deg < k) if k > 0 else np.zeros(0, np.int64)
+    ring = np.zeros(len(rows), dtype=bool)
+    if len(rows):
+        key = pi * n + pj
+        knn, exact = grid.block_knn(rows, k)
+        ring = ~exact
+        for t in np.flatnonzero(ring):
+            knn[t] = grid.exact_knn(int(rows[t]), k)
+        u, v, new, d = _floor_attempts(n, key, deg, rows, knn)
+        own = new.reshape(len(rows), k, 2)[:, :, 0].sum(axis=1)
+        order_bound = (own < k) & (d[::2 * k] + own >= width) & ~ring
+        if order_bound.any():
+            for t in np.flatnonzero(order_bound):
+                knn[t] = grid.exact_knn(int(rows[t]), k)
+            ring |= order_bound
+            u, v, new, d = _floor_attempts(n, key, deg, rows, knn)
+        width = _grown_width(d, width)
+        add = np.argsort(u[new] * n + v[new])
+        au, av = u[new][add], v[new][add]
+        at = np.searchsorted(key, au * n + av)
+        pi = np.insert(pi, at, au)
+        pj = np.insert(pj, at, av)
+        d2 = np.insert(d2, at, pair_sq_dists(pos, au, av))
+    if counts is not None:
+        counts["deficient"] = counts.get("deficient", 0) + len(rows)
+        counts["ring_fallbacks"] = (counts.get("ring_fallbacks", 0)
+                                    + int(ring.sum()))
+    slot = pi * width + segmented_arange(np.bincount(pi, minlength=n))
+    nbrs = np.zeros(n * width, dtype=np.int32)
+    mask = np.zeros(n * width, dtype=bool)
+    nd2 = np.zeros(n * width, dtype=np.float64)
+    nbrs[slot] = pj
+    mask[slot] = True
+    nd2[slot] = d2
+    return (nbrs.reshape(n, width), mask.reshape(n, width),
+            nd2.reshape(n, width))
 
 
 def sparse_range_graph(pos: np.ndarray, radio_range: float,
-                       min_degree: int, k_max: int) -> NeighborGraph:
+                       min_degree: int, k_max: int,
+                       counts: dict | None = None) -> NeighborGraph:
     """Neighbor-list twin of :func:`range_graph`: radio-range disk graph
     from a cell-list search (no O(n²) distance matrix), the same
-    min-degree patch (exact k nearest for deficient nodes, via expanding
-    cell rings), the same deterministic connectivity patch. With
-    ``k_max`` ≥ the realized max degree this is edge-for-edge identical
-    to the dense lane (pinned); tighter ``k_max`` keeps only each node's
-    nearest ``k_max`` in-range links — the O(n·k) memory cap."""
+    min-degree patch (exact k nearest for deficient nodes), the same
+    deterministic connectivity patch. With ``k_max`` ≥ the realized max
+    degree this is edge-for-edge identical to the dense lane (pinned);
+    tighter ``k_max`` keeps only each node's nearest ``k_max`` in-range
+    links — the O(n·k) memory cap. ``counts``: see
+    :func:`_floor_min_degree`."""
     n = pos.shape[0]
     grid = _CellGrid(pos, radio_range)
-    pi, pj = grid.candidate_pairs()
-    d2 = pair_sq_dists(pos, pi, pj)
-    keep = d2 <= radio_range * radio_range
-    pi, pj, d2 = pi[keep], pj[keep], d2[keep]
+    pi, pj, d2 = grid.range_pairs(radio_range * radio_range)
     pi, pj, d2 = _cap_degree_pairs(n, pi, pj, d2, k_max)
-    graph = neighbor_graph_from_pairs(n, pi, pj, d2, pos,
-                                      assume_sorted=True)
-    nbrs, mask, nd2 = _patch_min_degree_lists(
-        graph.nbrs, graph.nbr_mask, graph.nbr_d2, pos, grid,
-        min(min_degree, n - 1))
+    nbrs, mask, nd2 = _floor_min_degree(n, pi, pj, d2, pos, grid,
+                                        min(min_degree, n - 1), counts)
     nbrs, mask, nd2 = patch_connected_lists(nbrs, mask, nd2, pos)
     return NeighborGraph(nbrs=nbrs, nbr_mask=mask, positions=pos,
                          nbr_d2=nd2)
 
 
-def sparse_knn_graph(pos: np.ndarray, min_degree: int,
-                     k_max: int) -> NeighborGraph:
+def sparse_knn_graph(pos: np.ndarray, min_degree: int, k_max: int,
+                     counts: dict | None = None) -> NeighborGraph:
     """Neighbor-list twin of ``random_geometric_graph``'s body for given
     positions: symmetrized k-nearest-neighbor adjacency + connectivity
     patch, built from a cell-list search sized so the 3×3 block around a
     node is expected to hold ≳ 9·(k+2) candidates. Nodes whose k-th
     candidate isn't provably nearest fall back to the exact
     expanding-ring search. Bit-identical graphs to the dense lane
-    (``knn_adjacency`` + ``patch_connected``) — pinned."""
+    (``knn_adjacency`` + ``patch_connected``) — pinned. ``counts``: see
+    :func:`_floor_min_degree` (the re-floor after the cap)."""
     n = pos.shape[0]
     k = min(min_degree, n - 1)
     if k <= 0:
@@ -363,10 +524,8 @@ def sparse_knn_graph(pos: np.ndarray, min_degree: int,
     # the realized max degree (the dense-parity regime) both steps are
     # no-ops.
     pi, pj, d2u = _cap_degree_pairs(n, pi, pj, d2u, k_max)
-    graph = neighbor_graph_from_pairs(n, pi, pj, d2u, pos,
-                                      assume_sorted=True)
-    nbrs, mask, nd2 = _patch_min_degree_lists(
-        graph.nbrs, graph.nbr_mask, graph.nbr_d2, pos, grid, k)
+    nbrs, mask, nd2 = _floor_min_degree(n, pi, pj, d2u, pos, grid, k,
+                                        counts)
     nbrs, mask, nd2 = patch_connected_lists(nbrs, mask, nd2, pos)
     return NeighborGraph(nbrs=nbrs, nbr_mask=mask, positions=pos,
                          nbr_d2=nd2)
@@ -393,6 +552,7 @@ class StaticRegenMobility:
         self.cfg = cfg
         self.backend = backend
         self.k_max = k_max
+        self.floor_counts: dict[str, int] = {}
         self.regen_every = max(1, cfg.regen_every)
         self._round = 0
         self.n_regens = 0
@@ -432,12 +592,13 @@ class StaticRegenMobility:
         regen = rs % self.regen_every == 0
         k = int(regen.sum())
         fresh: list[ClientGraph] = []
+        self.floor_counts = {}
         if k:
             pos = rng.uniform(0.0, 1.0, size=(k, self.n, 2))
             if self.backend == "sparse":
                 # O(n·k) per frame — no (R, n, n) stack to batch over.
                 fresh = [sparse_knn_graph(pos[r], self.cfg.min_degree,
-                                          self.k_max)
+                                          self.k_max, self.floor_counts)
                          for r in range(k)]
             else:
                 fresh = _knn_graphs_batch(pos, self.cfg.min_degree)
@@ -483,6 +644,7 @@ class RandomWaypointMobility:
         self.cfg = cfg
         self.backend = backend
         self.k_max = k_max
+        self.floor_counts: dict[str, int] = {}
 
     def reset_positions(self, rng: np.random.Generator) -> np.ndarray:
         self.pos = rng.uniform(0.0, 1.0, size=(self.n, 2))
@@ -526,8 +688,7 @@ class RandomWaypointMobility:
         pos = np.empty((rounds, self.n, 2))
         for t in range(rounds):
             pos[t] = self.step_positions(rng)
-        return _range_rollout_graphs(pos, self.cfg, self.backend,
-                                     self.k_max)
+        return _range_rollout_graphs(self, pos)
 
     def _graph(self, pos: np.ndarray) -> ClientGraph:
         if self.backend == "sparse":
@@ -537,15 +698,18 @@ class RandomWaypointMobility:
                            self.cfg.min_degree)
 
 
-def _range_rollout_graphs(pos: np.ndarray, cfg: MobilityConfig,
-                          backend: str, k_max: int):
+def _range_rollout_graphs(model, pos: np.ndarray):
     """Rollout tail shared by the smooth models: dense batches the
     (R, n, n) construction; sparse builds each frame's O(n·k) neighbor
     lists (there is no quadratic stack to batch over — the per-frame
-    cell-list pass IS the batched form)."""
-    if backend == "sparse":
+    cell-list pass IS the batched form) and sums its floor counters
+    into ``model.floor_counts``."""
+    cfg = model.cfg
+    model.floor_counts = {}
+    if model.backend == "sparse":
         return [sparse_range_graph(pos[t], cfg.radio_range,
-                                   cfg.min_degree, k_max)
+                                   cfg.min_degree, model.k_max,
+                                   model.floor_counts)
                 for t in range(pos.shape[0])]
     return range_graphs_batch(pos, cfg.radio_range, cfg.min_degree)
 
@@ -565,6 +729,7 @@ class GaussMarkovMobility:
         self.cfg = cfg
         self.backend = backend
         self.k_max = k_max
+        self.floor_counts: dict[str, int] = {}
 
     def reset_positions(self, rng: np.random.Generator) -> np.ndarray:
         self.pos = rng.uniform(0.0, 1.0, size=(self.n, 2))
@@ -610,8 +775,7 @@ class GaussMarkovMobility:
         pos = np.empty((rounds, self.n, 2))
         for t in range(rounds):
             pos[t] = self._advance(noise[t])
-        return _range_rollout_graphs(pos, self.cfg, self.backend,
-                                     self.k_max)
+        return _range_rollout_graphs(self, pos)
 
     def _graph(self, pos: np.ndarray) -> ClientGraph:
         if self.backend == "sparse":
@@ -688,6 +852,7 @@ class TraceMobility:
         self.cfg = cfg
         self.backend = backend
         self.k_max = k_max
+        self.floor_counts: dict[str, int] = {}
         self.trace = load_trace(cfg.trace_path)
         if self.trace.shape[1] != n:
             raise ValueError(
@@ -720,8 +885,7 @@ class TraceMobility:
         self._t += rounds
         if rounds:
             self.pos = pos[-1]
-        return _range_rollout_graphs(pos, self.cfg, self.backend,
-                                     self.k_max)
+        return _range_rollout_graphs(self, pos)
 
     def _graph(self, pos: np.ndarray) -> ClientGraph:
         if self.backend == "sparse":

@@ -61,8 +61,9 @@ class Scenario:
         self.cfg = cfg
         self.telemetry = telemetry   # TelemetryRun or None (off):
         # schedule() emits "scenario_rollout" phase spans into it, with
-        # "mobility" and "links" spans inside — pure host-side control
-        # plane, no RNG or trajectory impact.
+        # "mobility" (meta: edges; on the sparse backend also the floor's
+        # deficient rows and ring_fallbacks) and "links" spans inside —
+        # pure host-side control plane, no RNG or trajectory impact.
         self.positions_only = bool(positions_only)
         self.mobility = build_mobility(n, cfg.mobility,
                                        backend=cfg.graph_backend,
@@ -157,6 +158,7 @@ class Scenario:
                         base = self.mobility.rollout(m, self._rng_mob)
                         if self.telemetry is not None:
                             sp.meta["edges"] = _edges(base)
+                            sp.meta.update(self.mobility.floor_counts)
                     if self.link is not None:
                         with self._phase("links", rounds=m):
                             eff = self.link.apply_dropouts_batch(

@@ -446,3 +446,223 @@ def test_cell_list_guard_rejects_effectively_dense_search():
 
     with pytest.raises(ValueError, match="candidate pairs"):
         _CellGrid(pos, 0.9).candidate_pairs(max_pairs=100_000)
+
+
+def test_range_pairs_guard_counts_the_full_search():
+    """The half-neighborhood search keeps the guard's meaning: the
+    directed candidates the 3×3 search would generate."""
+    pos = np.random.default_rng(0).uniform(0, 1, (4000, 2))
+    from repro.scenarios.mobility import _CellGrid
+
+    with pytest.raises(ValueError, match="candidate pairs"):
+        _CellGrid(pos, 0.9).range_pairs(0.81, max_pairs=100_000)
+    grid = _CellGrid(pos, 0.05)
+    full = len(grid.candidate_pairs()[0]) + len(pos)     # self pairs too
+    grid.range_pairs(0.0025, max_pairs=full)
+    with pytest.raises(ValueError, match="candidate pairs"):
+        grid.range_pairs(0.0025, max_pairs=full - 1)
+
+
+# ------------------------------ whole-array floor against the row loop --
+def _oracle_cap_degree_pairs(n, pi, pj, d2, k_max):
+    """The degree cap as the row-by-row construction had it: a full
+    (i, j) lexsort first, then the (d2, i) ranking of every row."""
+    order = np.lexsort((pj, pi))
+    pi, pj, d2 = pi[order], pj[order], d2[order]
+    deg = np.bincount(pi, minlength=n)
+    if not len(pi) or deg.max() <= k_max:
+        return pi, pj, d2
+    by_dist = np.lexsort((d2, pi))
+    rank = np.empty(len(pi), dtype=np.int64)
+    rank[by_dist] = G.segmented_arange(deg)
+    keep_dir = rank < k_max
+    key = pi * n + pj
+    ridx = np.searchsorted(key, pj * n + pi)
+    keep = keep_dir & keep_dir[ridx]
+    return pi[keep], pj[keep], d2[keep]
+
+
+def _oracle_patch_min_degree_lists(nbrs, mask, nd2, pos, grid, k):
+    """The min-degree floor row by row: each row below ``k`` gets its
+    ring-searched k nearest inserted one edge at a time (writes into
+    its inputs)."""
+    if k <= 0:
+        return nbrs, mask, nd2
+    deg = mask.sum(axis=1)
+    for i in np.flatnonzero(deg < k):
+        for j in grid.exact_knn(int(i), k):
+            e2 = float(pair_sq_dists(pos, np.asarray([i]),
+                                     np.asarray([j]))[0])
+            nbrs, mask, nd2 = G._insert_edge_lists(
+                nbrs, mask, nd2, int(i), int(j), e2)
+    return nbrs, mask, nd2
+
+
+def _oracle_sparse_range_graph(pos, radio, min_degree, k_max):
+    """(capped pairs, graph, deficient rows) of the row-by-row construction:
+    the full 3×3 directed search, the oracle cap, one pack, the loop."""
+    from repro.scenarios.mobility import _CellGrid
+
+    n = len(pos)
+    grid = _CellGrid(pos, radio)
+    pi, pj = grid.candidate_pairs()
+    d2 = pair_sq_dists(pos, pi, pj)
+    keep = d2 <= radio * radio
+    pairs = _oracle_cap_degree_pairs(n, pi[keep], pj[keep], d2[keep],
+                                     k_max)
+    g = G.neighbor_graph_from_pairs(n, *pairs, pos, assume_sorted=True)
+    k = min(min_degree, n - 1)
+    deficient = int((g.degree() < k).sum()) if k > 0 else 0
+    nbrs, mask, nd2 = _oracle_patch_min_degree_lists(
+        g.nbrs.copy(), g.nbr_mask.copy(), g.nbr_d2.copy(), pos, grid, k)
+    nbrs, mask, nd2 = patch_connected_lists(nbrs, mask, nd2, pos)
+    return pairs, NeighborGraph(nbrs=nbrs, nbr_mask=mask, positions=pos,
+                                nbr_d2=nd2), deficient
+
+
+def _gauss_markov_frames(n, degree, frames, seed):
+    """(radio range for the expected degree, gauss-markov positions)."""
+    from repro.scenarios.mobility import GaussMarkovMobility
+
+    radio = float(np.sqrt(degree / (np.pi * n)))
+    mob = GaussMarkovMobility(
+        n, MobilityConfig(model="gauss_markov", radio_range=radio),
+        backend="sparse")
+    rng = np.random.default_rng(seed)
+    mob.reset_positions(rng)
+    return radio, [mob.step_positions(rng).copy() for _ in range(frames)]
+
+
+def _floor_case(case):
+    """(radio, frames, min_degree, k_max, compare with the dense lane)."""
+    if case == "cell":           # the MLR cell's control plane
+        return (*_gauss_markov_frames(10_000, 12, 3, seed=15), 5, 32,
+                False)
+    if case == "deficient":      # expected degree 3: most rows short
+        return (*_gauss_markov_frames(3000, 3.0, 2, seed=16), 5, 32,
+                False)
+    if case == "cap":            # k_max 8 against expected degree 12
+        return (*_gauss_markov_frames(2000, 12, 2, seed=17), 5, 8, False)
+    if case == "corner":         # a bunch at a corner, rows on two edges
+        rng = np.random.default_rng(18)
+        edge = rng.uniform(0, 1, 300)
+        pos = np.concatenate([
+            rng.uniform(0.0, 0.03, (400, 2)),
+            np.stack([edge, np.ones(300)], axis=1),
+            np.stack([np.zeros(300), rng.uniform(0, 1, 300)], axis=1),
+            rng.uniform(0, 1, (1300, 2))])
+        return float(np.sqrt(12 / (np.pi * len(pos)))), [pos], 5, 32, \
+            False
+    seed = int(case.split("-")[1])   # small-<seed>: n 10–150
+    rng = np.random.default_rng(300 + seed)
+    n = int(rng.integers(10, 150))
+    pos = rng.uniform(0, 1, (n, 2))
+    radio = float(rng.uniform(0.04, 0.45))
+    k_max = n if seed % 2 == 0 else int(rng.integers(2, 8))
+    return radio, [pos], 5, k_max, k_max == n
+
+
+FLOOR_CASES = ["cell", "deficient", "cap", "corner"] + [
+    f"small-{s}" for s in range(8)]
+
+
+@pytest.mark.parametrize("case", FLOOR_CASES)
+def test_whole_array_floor_matches_row_loop(case):
+    """The half-neighborhood search, the cap and the whole-array
+    min-degree floor give the row-by-row construction's graph exactly: the
+    same (i, j, d2) pairs, neighbor lists, distances and packed width,
+    without writing into their inputs; the counters count the rows
+    below the floor and the ring searches."""
+    from repro.scenarios.mobility import (
+        _CellGrid,
+        _cap_degree_pairs,
+        _floor_min_degree,
+    )
+
+    radio, frames, min_degree, k_max, vs_dense = _floor_case(case)
+    ring = 0
+    for pos in frames:
+        n = len(pos)
+        want_pairs, want, deficient = _oracle_sparse_range_graph(
+            pos, radio, min_degree, k_max)
+        grid = _CellGrid(pos, radio)
+        pairs = _cap_degree_pairs(n, *grid.range_pairs(radio * radio),
+                                  k_max)
+        for a, b in zip(pairs, want_pairs):
+            np.testing.assert_array_equal(a, b)
+        kept = [a.copy() for a in pairs]
+        _floor_min_degree(n, *pairs, pos, grid, min(min_degree, n - 1))
+        for a, b in zip(pairs, kept):
+            np.testing.assert_array_equal(a, b)
+        counts = {}
+        got = sparse_range_graph(pos, radio, min_degree, k_max, counts)
+        for name in ("nbrs", "nbr_mask", "nbr_d2"):
+            np.testing.assert_array_equal(getattr(got, name),
+                                          getattr(want, name))
+        assert counts["deficient"] == deficient
+        assert 0 <= counts["ring_fallbacks"] <= deficient
+        ring += counts["ring_fallbacks"]
+        if n <= 2500:
+            _check_invariants(got)
+        if vs_dense:
+            np.testing.assert_array_equal(
+                got.to_dense().adjacency,
+                range_graph(pos, radio, min_degree).adjacency)
+    if case == "deficient":
+        assert ring > 0
+
+
+# sha256 of a 16-round schedule of the MLR cell's scenario at n = 2000
+# (seed 2147483911), recorded with the row-by-row range-graph construction:
+# every graph's neighbor lists, mask, distances and positions.
+MLR_CELL_SCHEDULE_SHA256 = {
+    True: "69a3c8bfb2f18c4133944678a6db96686d6db85260bb49909561f8bcd37e6dd1",
+    False: "cc10aa15846e414b00ecac3240cad6859f9acec17305878b9c9cf9d334552c12",
+}
+
+
+@pytest.mark.parametrize("links", [True, False])
+def test_mlr_cell_schedule_pinned(links):
+    """The MLR cell's control plane (gauss-markov, sparse, k ≤ 32,
+    chunks of 8, expected degree 12) rolls out the recorded graphs:
+    what the walk and, with links on, the link layer are handed did
+    not move."""
+    import hashlib
+
+    n = 2000
+    cfg = ScenarioConfig(
+        name="mlr_pin",
+        mobility=MobilityConfig(model="gauss_markov",
+                                radio_range=float(np.sqrt(12 / (np.pi * n)))),
+        links=LinkConfig(enabled=links, dropout=links),
+        graph_backend="sparse", neighbor_k_max=32, rollout_chunk=8)
+    h = hashlib.sha256()
+    for g in Scenario(n, cfg, seed=2147483911).schedule(16):
+        for a in (g.nbrs, g.nbr_mask, g.nbr_d2, g.positions):
+            h.update(str((a.shape, a.dtype.str)).encode())
+            h.update(np.ascontiguousarray(a).tobytes())
+    assert h.hexdigest() == MLR_CELL_SCHEDULE_SHA256[links]
+
+
+@pytest.mark.parametrize("seed", [109, 597, 747])
+def test_floor_width_follows_the_ring_order(monkeypatch, seed):
+    """Where a row's own insertions can fill it, the order of its k
+    nearest decides the packed width the row loop reaches: with the
+    ring search handing its neighbors farthest first (layouts where
+    that order changes the width), the whole-array floor still packs
+    the loop's width."""
+    from repro.scenarios.mobility import _CellGrid
+
+    exact_knn = _CellGrid.exact_knn
+    monkeypatch.setattr(_CellGrid, "exact_knn",
+                        lambda self, i, k: exact_knn(self, i, k)[::-1])
+    rng = np.random.default_rng(seed)
+    n = int(rng.integers(10, 60))
+    pos = rng.uniform(0, 1, (n, 2))
+    radio = float(np.sqrt(rng.uniform(1.0, 6) / (np.pi * n)))
+    k_max = int(rng.integers(3, 8))
+    _, want, _ = _oracle_sparse_range_graph(pos, radio, 5, k_max)
+    got = sparse_range_graph(pos, radio, 5, k_max)
+    for name in ("nbrs", "nbr_mask", "nbr_d2"):
+        np.testing.assert_array_equal(getattr(got, name),
+                                      getattr(want, name))

@@ -469,6 +469,8 @@ def test_schedule_spans_record_their_parent(fed, tmp_path, fleet):
     assert sum(e["name"] == "readback" for e in phases) == 2
     mob = [e for e in phases if e["name"] == "mobility"]
     assert all(e["edges"] > 0 for e in mob)
+    # the sparse min-degree floor's counters ride on the same span
+    assert all(0 <= e["ring_fallbacks"] <= e["deficient"] for e in mob)
     # round 0 (single walker) or the first K rounds (round-robin fleet)
     # serve the current graph; the others are rolled out
     assert sum(e["rounds"] for e in mob) == 8 - max(fleet, 1)
