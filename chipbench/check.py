@@ -95,9 +95,10 @@ def compare(prog, ref) -> dict:
             "first_eval_gap": eval_gap(prog.evals[:1], ref.evals[:1])}
 
 
-def load_limits(workload: str) -> dict:
-    """{number: limit} of a cell; {} when its limits are not set yet."""
-    path = os.path.join(HERE, "limits", f"{workload}.json")
+def load_limits(workload: str, root: str = os.path.dirname(HERE)) -> dict:
+    """{number: limit} of a cell under ``root``; {} when its limits are
+    not set yet."""
+    path = os.path.join(root, "chipbench", "limits", f"{workload}.json")
     if not os.path.exists(path):
         return {}
     with open(path) as f:

@@ -1,6 +1,11 @@
 """Operations and bytes a round needs, from the configuration's shapes.
 
-They count what the algorithm requires, whatever implements it:
+They count what the algorithm requires, whatever implements it. Each
+model kind (``kinds/<kind>.py``) gives its own ``forward_macs``,
+``n_params`` (the parameters of one plane row), ``round_flops`` and
+``round_bytes``; the functions of the same names here find the kind by
+the model's ``kind``. The helpers below are the counts of a kind whose
+every weight is trained and is a plane row:
 
 * FLOPs: the forward and backward passes of every zone member's
   minibatch (backward = 2x forward, nothing recomputed), 2 FLOPs per
@@ -11,32 +16,29 @@ They count what the algorithm requires, whatever implements it:
 """
 from __future__ import annotations
 
-import math
+from . import kinds
+
+
+def _kind(model: dict):
+    return kinds.load(model["kind"])
 
 
 def forward_macs(model: dict) -> int:
     """Multiply-accumulates of one sample's forward pass."""
-    if model["kind"] == "mlr":
-        return int(model["features"]) * int(model["n_classes"])
-    h, w, cin = model["input_shape"]
-    k, c1, c2 = model["kernel"], model["conv1_channels"], model[
-        "conv2_channels"]
-    fc, out = model["fc_width"], model["n_classes"]
-    conv1 = h * w * c1 * k * k * cin                 # SAME, stride 1
-    conv2 = (h // 2) * (w // 2) * c2 * k * k * c1   # after 2x2 pooling
-    dense = (h // 4) * (w // 4) * c2 * fc + fc * out
-    return conv1 + conv2 + dense
+    return _kind(model).forward_macs(model)
 
 
 def n_params(model: dict) -> int:
-    if model["kind"] == "mlr":
-        return (int(model["features"]) + 1) * int(model["n_classes"])
-    h, w, cin = model["input_shape"]
-    k, c1, c2 = model["kernel"], model["conv1_channels"], model[
-        "conv2_channels"]
-    fc, out = model["fc_width"], model["n_classes"]
-    return ((k * k * cin + 1) * c1 + (k * k * c1 + 1) * c2
-            + ((h // 4) * (w // 4) * c2 + 1) * fc + (fc + 1) * out)
+    """Parameters of one client's plane row."""
+    return _kind(model).n_params(model)
+
+
+def round_flops(model: dict, traffic: dict, batch: int) -> float:
+    return _kind(model).round_flops(model, traffic, batch)
+
+
+def round_bytes(model: dict, traffic: dict, batch: int) -> float:
+    return _kind(model).round_bytes(model, traffic, batch)
 
 
 def zones_per_round(traffic: dict) -> int:
@@ -46,16 +48,17 @@ def zones_per_round(traffic: dict) -> int:
     return 1
 
 
-def round_flops(model: dict, traffic: dict, batch: int) -> float:
+def all_trained_flops(traffic: dict, batch: int, macs: int) -> float:
+    """FLOPs of a round whose every weight takes a gradient, from the
+    forward MACs of one sample."""
     zone = int(traffic["zone_size"])
-    return (zones_per_round(traffic) * zone * batch * 6.0
-            * forward_macs(model))
+    return zones_per_round(traffic) * zone * batch * 6.0 * macs
 
 
-def round_bytes(model: dict, traffic: dict, batch: int) -> float:
+def all_rows_bytes(traffic: dict, batch: int, row_params: int,
+                   sample_bytes: int) -> float:
+    """Least bytes of a round whose every weight is a plane row of
+    ``row_params`` float32, from the bytes of one training sample."""
     zone = int(traffic["zone_size"])
-    p = n_params(model)
-    shape = model.get("input_shape") or [model["features"]]
-    sample = 4 * math.prod(shape) + 4                  # float32 x, int32 y
-    return zones_per_round(traffic) * ((4 * zone + 2) * p * 4
-                                       + zone * batch * sample)
+    return zones_per_round(traffic) * ((4 * zone + 2) * row_params * 4
+                                       + zone * batch * sample_bytes)

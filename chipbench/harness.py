@@ -3,9 +3,12 @@
 A cell is a configuration (``configs/<config>.json``: model, data,
 population, client plane, solver) under a traffic mix
 (``traffic/<traffic>.json``: walkers, zone size, mobility, eval
-cadence). Set-up builds the data and the weights from the seed, the
-trainer from both files, and drives ``run_simulation`` twice: once to
-compile the cell's chunk and eval, once to measure a rate. The window
+cadence). The configuration's ``model.kind`` names the module
+``kinds/<kind>.py`` that makes the weights and data from the seed, gives
+the program its model and the reference its mathematics. Set-up builds
+the data and the weights from the seed, the trainer from both files,
+and drives ``run_simulation`` twice: once to compile the cell's chunk
+and eval, once to measure a rate. The window
 is one ``run_simulation`` call of a whole number of eval windows sized
 to last about ``seconds``, with no telemetry and no profiler attached,
 and with a compile counter around it.
@@ -33,7 +36,7 @@ import time
 import jax
 import numpy as np
 
-from . import check, data, reference, trace
+from . import check, data, kinds, reference, trace
 from .compiles import compile_log
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -59,6 +62,8 @@ class Cell:
     traffic: dict
     end_to_end: list
     per_layer: list
+    root: str                    # the checkout the cell's files are in
+    kind: object                 # the module of ``model.kind``
 
 
 def load_bench(root: str = REPO) -> dict:
@@ -85,13 +90,14 @@ def resolve(bench: dict, workload: str, root: str = REPO) -> Cell:
     def applies(m):
         return workload in m.get("workloads", [workload])
 
+    config = _load_json(os.path.join(root, c["file"]))
     return Cell(
-        name=workload, chips=int(w["chips"]),
-        config=_load_json(os.path.join(root, c["file"])),
+        name=workload, chips=int(w["chips"]), config=config,
         traffic=_load_json(os.path.join(root, "chipbench", "traffic",
                                         w["traffic"] + ".json")),
         end_to_end=[m for m in bench["end_to_end"] if applies(m)],
-        per_layer=[m for m in bench["per_layer"] if applies(m)])
+        per_layer=[m for m in bench["per_layer"] if applies(m)],
+        root=root, kind=kinds.load(config["model"]["kind"], root))
 
 
 def metric_reader(name: str, root: str = REPO):
@@ -105,14 +111,22 @@ def metric_reader(name: str, root: str = REPO):
 
 
 # ---------------------------------------------------------------- build --
+#: where the reference runs, by the configuration's ``reference_device``:
+#: the host's CPU, or the first accelerator at ``highest`` precision for
+#: a model the host cannot replay within a run
+REFERENCE_DEVICES = ("cpu", "accelerator")
+
+
 @dataclasses.dataclass
 class Built:
     trainer: object
-    weights: dict
+    weights: dict                # the trainable tree: a row of every plane
+    frozen: object               # the kind's tree held once, or None
     dense: tuple                 # DeviceData columns
     solver: reference.Solver
     fleet: bool
     sim_seeds: list
+    reference_device: str
 
 
 def _scenario(cfg: dict, traffic: dict, n: int, name: str):
@@ -135,24 +149,21 @@ def build(cell: Cell, seed: int) -> Built:
     from repro.fl.base import DeviceData
     from repro.fl.fleet_trainer import FleetRWSADMMTrainer
     from repro.fl.rwsadmm_trainer import RWSADMMTrainer
-    from repro.models.small import get_model
 
-    cfg, tr = cell.config, cell.traffic
+    cfg, tr, kind = cell.config, cell.traffic, cell.kind
     s_data, s_w, s_trainer, s_sim = data.sub_seeds(seed, 4)
     mcfg, sol = cfg["model"], cfg["solver"]
-    weights = data.init_weights(jax.random.PRNGKey(s_w),
-                                data.model_key(mcfg))
-    shape = tuple(mcfg.get("input_shape") or (mcfg["features"],))
-    model = dataclasses.replace(
-        get_model(mcfg["kind"], shape, int(mcfg["n_classes"])),
-        init=lambda key: weights)
+    weights, frozen = kind.weights(jax.random.PRNGKey(s_w), mcfg)
+    model = kind.program_model(mcfg, weights, frozen)
     n = int(cfg["n_clients"])
     if cfg["plane"] != "dense":
         raise ValueError(f"{cfg['name']}: only the dense client plane is "
                          f"supported, not {cfg['plane']!r}")
-    make = (data.stacked_images if cfg["data"]["kind"] == "synthetic_images"
-            else data.stacked_synthetic)
-    dense = make(cfg["data"], n, s_data)
+    ref_device = cfg.get("reference_device", "cpu")
+    if ref_device not in REFERENCE_DEVICES:
+        raise ValueError(f"{cfg['name']}: reference_device "
+                         f"{ref_device!r} is not one of {REFERENCE_DEVICES}")
+    dense = kind.columns(cfg, n, s_data)
     hp = RWSADMMHparams(beta=float(sol["beta"]), kappa=float(sol["kappa"]),
                         kappa_decay=float(sol["kappa_decay"]),
                         epsilon=float(sol["epsilon"]))
@@ -171,11 +182,11 @@ def build(cell: Cell, seed: int) -> Built:
     else:
         trainer = RWSADMMTrainer(model, DeviceData(*dense), hp, **kw)
     solver = reference.Solver(
-        model=mcfg["kind"], beta=hp.beta, kappa=hp.kappa,
+        model=kind, beta=hp.beta, kappa=hp.kappa,
         kappa_decay=hp.kappa_decay, eps_half=hp.eps_half, n_total=float(n),
         batch=int(sol["batch_size"]))
-    return Built(trainer, weights, dense, solver, fleet,
-                 [s_sim + i for i in range(3)])
+    return Built(trainer, weights, frozen, dense, solver, fleet,
+                 [s_sim + i for i in range(3)], ref_device)
 
 
 # ------------------------------------------------------------- recorder --
@@ -311,14 +322,16 @@ class Run:
 
     def reference(self, **variant) -> reference.Outputs:
         """The reference's outputs over the checked schedules, on the
-        host's CPU; ``variant`` sets ``dtype`` / ``half_batch``."""
+        configuration's ``reference_device``; ``variant`` sets
+        ``dtype`` / ``half_batch`` / ``frozen``."""
         b = self.b
         sv = dataclasses.replace(b.solver, **variant)
-        cpu = jax.devices("cpu")[0]
-        weights = jax.device_put(b.weights, cpu)
-        with jax.default_device(cpu):
-            xt, yt, nt, xe, ye, me = jax.device_put(b.dense, cpu)
-            return reference.simulate(sv, weights, (xt, yt, nt),
+        dev = (jax.devices("cpu")[0] if b.reference_device == "cpu"
+               else jax.devices()[0])
+        weights, frozen = jax.device_put((b.weights, b.frozen), dev)
+        with jax.default_device(dev):
+            xt, yt, nt, xe, ye, me = jax.device_put(b.dense, dev)
+            return reference.simulate(sv, weights, frozen, (xt, yt, nt),
                                       self.checked, fleet=b.fleet,
                                       eval_data=(xe, ye, me))
 
@@ -365,8 +378,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace_on: bool, *,
     numbers = run.numbers(run.reference())
     print("readings " + " ".join(f"{k}={v!r}" for k, v in numbers.items()),
           file=sys.stderr)
-    ok, shown = check.verdict(numbers, check.load_limits(cell.name)
-                              if limits is None else limits)
+    if limits is None:
+        limits = check.load_limits(cell.name, cell.root)
+    ok, shown = check.verdict(numbers, limits)
     dev = jax.devices()[0]
     out = {"correct": bool(ok and failed == 0), "attempted": attempted,
            "failed": failed, "metrics": {},
@@ -393,7 +407,6 @@ def _traced_window(run: Run, rounds: int):
     window_s)."""
     from repro.telemetry import TelemetryRun, read_events
 
-    from . import counts
     from .peaks import peak
 
     cell = run.cell
@@ -422,10 +435,10 @@ def _traced_window(run: Run, rounds: int):
         "events": events, "devices": devs, "window": (win.start, win.end),
         "rounds": rounds, "chips": cell.chips,
         "telemetry": list(read_events(tel.events_path)),
-        "round_flops": counts.round_flops(mcfg, cell.traffic,
-                                          int(sol["batch_size"])),
-        "round_bytes": counts.round_bytes(mcfg, cell.traffic,
-                                          int(sol["batch_size"])),
+        "round_flops": cell.kind.round_flops(mcfg, cell.traffic,
+                                             int(sol["batch_size"])),
+        "round_bytes": cell.kind.round_bytes(mcfg, cell.traffic,
+                                             int(sol["batch_size"])),
         "peak": peak(jax.devices()[0].device_kind),
     }
     from .readers import device_ns

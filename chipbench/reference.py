@@ -1,19 +1,20 @@
 """Plain reference of what a timed ``run_simulation`` computes.
 
 Independent of the program: nothing here imports ``repro``. The
-benchmark runs it on the host's CPU, where float32 is float32 whatever
-the matmul precision setting, once the measured window has closed. It
-replays
-recorded walk schedules (which client zones were visited, with which
-per-round keys) through straightforward ``jax.numpy`` code:
+benchmark runs it once the measured window has closed, on the host's
+CPU, where float32 is float32 whatever the matmul precision setting, or
+on the accelerator at ``highest`` precision (the configuration's
+``reference_device``). It replays recorded walk schedules (which client
+zones were visited, with which per-round keys) through straightforward
+``jax.numpy`` code:
 
-* the paper CNN (App. D.1: two 5x5 SAME convolutions with ReLU and 2x2
-  max-pooling, FC-512, 10-way head, dropout 25% / 50% in training) and
-  multinomial logistic regression;
+* the model's forward pass and loss from its kind module
+  (``kinds/<kind>.py``: ``loss`` and ``eval_stats``), over the
+  trainable tree, with the kind's frozen tree closed over;
 * per client, a minibatch drawn uniformly with replacement from its
   training rows by ``randint(key, (B,), 0, n_train)``, the training
-  forward pass using the same key for dropout, the mean cross-entropy
-  and its gradient;
+  forward pass using the same key for dropout, the mean loss and its
+  gradient;
 * the RWSADMM closed-form round (paper Eq. 10/11, 15, 14/31, with
   ε/2 in the reformulated constraint and the y fold scaled by 1/n):
       s  = sign(y − x'),  c(x, z) = x − (z/β + ε/2)·sign(y − x)
@@ -40,44 +41,10 @@ import jax.numpy as jnp
 import numpy as np
 
 
-# -------------------------------------------------------------- models --
-def _conv(x, w, b):
-    y = jax.lax.conv_general_dilated(
-        x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    return y + b
-
-
-def _pool(x):
-    return jax.lax.reduce_window(x, -jnp.inf,
-                                 jax.lax.max, (1, 2, 2, 1), (1, 2, 2, 1),
-                                 "VALID")
-
-
-def _dropout(x, key, keep):
-    m = jax.random.bernoulli(key, keep, x.shape)
-    return jnp.where(m, x / jnp.asarray(keep, x.dtype), 0.0).astype(x.dtype)
-
-
-def cnn_logits(p, x, key=None):
-    h = _pool(jax.nn.relu(_conv(x, p["conv1"]["w"], p["conv1"]["b"])))
-    if key is not None:
-        h = _dropout(h, jax.random.fold_in(key, 1), 0.75)
-    h = _pool(jax.nn.relu(_conv(h, p["conv2"]["w"], p["conv2"]["b"])))
-    h = h.reshape(h.shape[0], -1)
-    h = jax.nn.relu(h @ p["fc"]["w"] + p["fc"]["b"])
-    if key is not None:
-        h = _dropout(h, jax.random.fold_in(key, 2), 0.5)
-    return h @ p["out"]["w"] + p["out"]["b"]
-
-
-def mlr_logits(p, x, key=None):
-    return x.reshape(x.shape[0], -1) @ p["linear"]["w"] + p["linear"]["b"]
-
-
-LOGITS = {"cnn": cnn_logits, "mlr": mlr_logits}
-
-
-def _xent(logits, labels, mask=None):
+# ------------------------------------------- one label a sample --
+def xent(logits, labels, mask=None):
+    """Mean cross-entropy of one label a row (over the rows ``mask``
+    keeps), in float32: the loss of kinds whose sample has one label."""
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     nll = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
     if mask is None:
@@ -85,7 +52,7 @@ def _xent(logits, labels, mask=None):
     return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
-def _acc(logits, labels, mask):
+def accuracy(logits, labels, mask):
     hit = (jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32)
     return jnp.sum(hit * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
@@ -95,7 +62,7 @@ def _acc(logits, labels, mask):
 class Solver:
     """The closed-form solver's constants and the run's shape."""
 
-    model: str           # "cnn" | "mlr"
+    model: object        # the kind's module (``kinds/<kind>.py``)
     beta: float
     kappa: float
     kappa_decay: float
@@ -122,21 +89,20 @@ def _zone_update(sv: Solver, x, z, y, g, kappa):
     return x_new, z_new, c_new - c_old
 
 
-def _client_grad(sv: Solver, params, data, client, key):
+def _client_grad(sv: Solver, params, frozen, data, client, key):
     x_tr, y_tr, n_tr = data
     idx = jax.random.randint(key, (sv.batch,), 0, n_tr[client])
     if sv.half_batch:
         idx = idx[: sv.batch // 2]
     xb, yb = x_tr[client, idx], y_tr[client, idx]
-    logits = LOGITS[sv.model]
 
     def loss(p):
-        return _xent(logits(p, xb, key), yb)
+        return sv.model.loss(p, frozen, xb, yb, key)
 
     return jax.value_and_grad(loss)(params)
 
 
-def _zones_step(sv: Solver, data, carry, per):
+def _zones_step(sv: Solver, frozen, data, carry, per):
     """One round of K ≥ 1 disjoint zones: (X, Z, tokens, kappa, visited)
     with ``idx``/``mask`` (K, Zs) rows of the compact client table."""
     X, Zs, tokens, kappa, visited = carry
@@ -148,7 +114,7 @@ def _zones_step(sv: Solver, data, carry, per):
     # One member at a time: a plain (unbatched) forward and backward pass.
     flat = lambda t: _tmap(lambda l: l.reshape((K * zs,) + l.shape[2:]), t)
     losses, grads = jax.lax.map(
-        lambda a: _client_grad(sv, a[0], data, a[1], a[2]),
+        lambda a: _client_grad(sv, a[0], frozen, data, a[1], a[2]),
         (flat(act_x), idx.reshape(-1), keys.reshape(K * zs, -1)))
     losses = losses.reshape(K, zs)
     grads = _tmap(lambda l: l.reshape((K, zs) + l.shape[1:]), grads)
@@ -186,33 +152,30 @@ def _zones_step(sv: Solver, data, carry, per):
 
 
 @functools.partial(jax.jit, static_argnames=("sv",))
-def _run_rounds(sv: Solver, X, Zs, tokens, kappa, visited, data, idx, mask,
-                keys, sync):
+def _run_rounds(sv: Solver, X, Zs, tokens, kappa, visited, frozen, data, idx,
+                mask, keys, sync):
     (X, Zs, tokens, kappa, visited), losses = jax.lax.scan(
-        functools.partial(_zones_step, sv, data),
+        functools.partial(_zones_step, sv, frozen, data),
         (X, Zs, tokens, kappa, visited), (idx, mask, keys, sync))
     return X, Zs, tokens, kappa, visited, losses
 
 
 @functools.partial(jax.jit, static_argnames=("sv",))
-def _first_grad_norms(sv: Solver, params, data, client, key):
+def _first_grad_norms(sv: Solver, params, frozen, data, client, key):
     """Per-leaf norm of one client's first minibatch gradient."""
-    _, g = _client_grad(sv, params, data, client, key)
+    _, g = _client_grad(sv, params, frozen, data, client, key)
     return _tmap(lambda l: jnp.sqrt(jnp.sum(l.astype(jnp.float32) ** 2)), g)
 
 
 @functools.partial(jax.jit, static_argnames=("model",))
-def _eval_clients(model, X, token, visited, rows, x_te, y_te, m_te):
+def _eval_clients(model, X, token, visited, frozen, rows, x_te, y_te, m_te):
     """Per-client (acc, loss) of the personalized model and the token,
     one client at a time over ``rows`` of the compact table."""
-    logits = LOGITS[model]
 
     def one(r):
         p = _tmap(lambda x, t: jnp.where(visited[r], x[r], t), X, token)
-        lp = logits(p, x_te[r])
-        lg = logits(token, x_te[r])
-        return (_acc(lp, y_te[r], m_te[r]), _xent(lp, y_te[r], m_te[r]),
-                _acc(lg, y_te[r], m_te[r]), _xent(lg, y_te[r], m_te[r]))
+        return (model.eval_stats(p, frozen, x_te[r], y_te[r], m_te[r])
+                + model.eval_stats(token, frozen, x_te[r], y_te[r], m_te[r]))
 
     return jax.lax.map(one, rows)
 
@@ -255,19 +218,22 @@ def change_norms(X, Zs, tokens, w, rows) -> dict:
     return out
 
 
-def simulate(sv: Solver, weights, data, schedules, *, fleet: bool,
+def simulate(sv: Solver, weights, frozen, data, schedules, *, fleet: bool,
              eval_data) -> Outputs:
     """Replay ``schedules`` (one per chunk, a snapshot after each) from
-    ``weights`` over every client.
+    ``weights`` (the trainable tree) over every client.
 
+    frozen: the kind's tree held once beside the planes, or None.
     data: (x_train, y_train, n_train) of the clients.
     schedules: dicts with ``idx``/``mask`` (R, Z) or (R, K, Z) of client
         ids, ``keys`` (R, 2) uint32 and ``sync`` (R,).
     eval_data: (x_test, y_test, mask_test) of the clients.
     """
     dt = jnp.dtype(sv.dtype)
-    cast = lambda t: _tmap(lambda l: l.astype(dt), t)
-    w = cast(weights)
+    # Floating leaves in the reference's type; ids and labels as they are.
+    cast = lambda t: _tmap(lambda l: l.astype(dt) if jnp.issubdtype(
+        l.dtype, jnp.floating) else l, t)
+    w, frozen = cast(weights), cast(frozen)
     n_rows = data[0].shape[0]
     X0 = _tmap(lambda l: jnp.broadcast_to(l, (n_rows,) + l.shape), w)
     X, Zs = X0, _tmap(jnp.zeros_like, X0)
@@ -275,8 +241,8 @@ def simulate(sv: Solver, weights, data, schedules, *, fleet: bool,
     tokens = _tmap(lambda l: jnp.broadcast_to(l, (K,) + l.shape), w)
     kappa = jnp.asarray(sv.kappa, dt)
     visited = jnp.zeros((n_rows,), bool)
-    tdata = (data[0].astype(dt), data[1], data[2])
-    edata = (eval_data[0].astype(dt), eval_data[1], eval_data[2])
+    tdata = (cast(data[0]), data[1], data[2])
+    edata = (cast(eval_data[0]), eval_data[1], eval_data[2])
     rows = jnp.arange(n_rows)
     losses, evals = [], []
     first = None
@@ -290,10 +256,11 @@ def simulate(sv: Solver, weights, data, schedules, *, fleet: bool,
                 live = int(np.flatnonzero(mask[0].reshape(-1) > 0)[0])
                 key0 = jax.random.split(jnp.asarray(s["keys"][0]),
                                         idx.shape[1] * idx.shape[2])[live]
-                first = _first_grad_norms(sv, w, tdata,
+                first = _first_grad_norms(sv, w, frozen, tdata,
                                           int(idx[0].reshape(-1)[live]), key0)
             X, Zs, tokens, kappa, visited, ls = _run_rounds(
-                sv, X, Zs, tokens, kappa, visited, tdata, jnp.asarray(idx),
+                sv, X, Zs, tokens, kappa, visited, frozen, tdata,
+                jnp.asarray(idx),
                 jnp.asarray(mask.astype(dt)), jnp.asarray(s["keys"]),
                 jnp.asarray(s["sync"], np.float32))
             losses.append(np.asarray(ls, np.float32))
@@ -301,7 +268,7 @@ def simulate(sv: Solver, weights, data, schedules, *, fleet: bool,
                         .astype(dt), tokens)
             acc_p, loss_p, acc_g, loss_g = (
                 np.asarray(v, np.float64) for v in _eval_clients(
-                    sv.model, X, tok, visited, rows, *edata))
+                    sv.model, X, tok, visited, frozen, rows, *edata))
             evals.append({"acc_personalized": float(acc_p.mean()),
                           "loss_personalized": float(loss_p.mean()),
                           "acc_global": float(acc_g.mean()),

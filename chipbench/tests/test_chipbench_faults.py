@@ -7,7 +7,6 @@ timed path broken underneath is not — a step that returns its state
 unchanged, and half of each minibatch left out with the mean taken over
 the rest. The control (the reference in bfloat16 in the program's
 place) is not correct either. The limits are the cells' own."""
-import copy
 import os
 import time
 
@@ -32,17 +31,7 @@ def tiny(name):
     else:
         cell = harness.resolve(BENCH, name)
     cell.name = name
-    cfg, tr = copy.deepcopy(cell.config), copy.deepcopy(cell.traffic)
-    tr["eval_every"] = 2
-    if cfg["data"]["kind"] == "synthetic_images":
-        cfg["n_clients"] = 6
-        cfg["data"].update(n_samples=240, input_shape=[8, 8, 3])
-        cfg["model"]["input_shape"] = [8, 8, 3]
-        tr["zone_size"] = 2 if tr["walkers"] > 1 else 3
-    else:
-        cfg["n_clients"] = 300
-        tr["zone_size"] = 3
-    cell.config, cell.traffic = cfg, tr
+    cell.config, cell.traffic = cell.kind.shrink(cell.config, cell.traffic)
     return cell
 
 
